@@ -17,8 +17,7 @@ import argparse
 import json
 import os
 
-# must import dryrun FIRST: it pins XLA_FLAGS to 512 host devices
-from repro.launch import dryrun  # noqa: E402
+from repro.launch import dryrun
 
 
 CELLS = {
@@ -154,6 +153,7 @@ def run_cell(name: str, spec: dict, out_dir: str) -> list[dict]:
 
 
 def main():
+    dryrun.force_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None, choices=list(CELLS))
     ap.add_argument("--out", default="results/perf")

@@ -82,6 +82,9 @@ def main() -> None:
                          "are {name, us_per_call, derived{...}} records")
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     from benchmarks import (
         arith,
         autotune,

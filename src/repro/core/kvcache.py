@@ -499,11 +499,11 @@ class FusedBitPlaneCacheFormat(BitPlaneCacheFormat):
     ``int4_bp`` (it IS a :class:`BitPlaneCacheFormat`); the difference is
     pure kernel policy: GQA decode routes the whole qk → masked softmax →
     av read through ONE Pallas pass per (batch × kv-head) row
-    (:func:`repro.kernels.ops.plane_decode_attention`), contracting
-    directly on the stored planes — one integer qk contraction, one
-    plane-folded av contraction, per-slot scales folded after the integer
-    math.  The jnp plane math of the parent class is the reference
-    semantics this kernel reproduces (within softmax rounding); MLA decode
+    (:func:`repro.kernels.ops.plane_decode_attention`), reading the stored
+    planes directly — one integer qk contraction, one av contraction,
+    per-slot scales folded after the integer math.  The jnp plane math of
+    the parent class is the reference semantics this kernel reproduces
+    (within softmax rounding); MLA decode
     keeps the parent's qk/av because its score mixes a float rope term
     between the two.
     """
@@ -520,14 +520,14 @@ class FusedBitPlaneCacheFormat(BitPlaneCacheFormat):
         qq = jnp.clip(
             jnp.round(q.astype(jnp.float32) / qq_scale[..., None]), -8, 7
         ).astype(jnp.int8)
-        q_planes = bitplane.encode(bitplane.pad_to_word(qq))  # [B,H,G,4,Fw]
+        qq = bitplane.pad_to_word(qq)  # [B, H, G, Fw·32]
         k_planes = _to_l_minor(k_store[""], 2)  # [B, H, L, 4, Fw]
         k_scale = _to_l_minor(k_store["_scale"], 0)  # [B, H, L]
         v_planes = _to_l_minor(v_store[""], 2)
         v_scale = _to_l_minor(v_store["_scale"], 0)
         l, fw = k_planes.shape[2], k_planes.shape[-1]
         out = ops.plane_decode_attention(
-            q_planes.reshape(b * h, g, 4, fw),
+            qq.reshape(b * h, g, fw * bitplane.WORD),
             qq_scale.reshape(b * h, g),
             k_planes.reshape(b * h, l, 4, fw),
             k_scale.reshape(b * h, l),

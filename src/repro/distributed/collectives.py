@@ -24,7 +24,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import quant
@@ -89,11 +88,11 @@ def compressed_psum_tree(
         spec = P()  # replicated within the pod slice
 
         @functools.partial(
-            shard_map,
+            jax.shard_map,
             mesh=mesh,
             in_specs=(spec,),
             out_specs=spec,
-            check_rep=False,
+            check_vma=False,
         )
         def inner(v):
             return compressed_psum(v, axis_name, chunk=chunk, key=k)

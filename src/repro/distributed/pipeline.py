@@ -28,7 +28,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -54,11 +53,11 @@ def pipeline_apply(
     p = mesh.shape[axis]
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     def run(params_local, xs):
         # params_local: [1, ...] stage slice; xs: [M, mb, ...]

@@ -11,7 +11,8 @@ int8 matmul of 0/1 bit matrices — work the MXU executes at full int8 rate.
 Per grid step ``(i, j, kk)`` the kernel stages a ``(bm, 4, bkw)``
 activation-plane tile and a ``(bn, 4, bkw)`` weight-plane tile into VMEM,
 unpacks each uint32 word tile into 0/1 int8 bit matrices ``[bm, bkw·32]`` /
-``[bn, bkw·32]`` (VPU shift-and-mask, the transposed-load analogue), then
+``[bn, bkw·32]`` (VPU shift-and-mask, the transposed-load analogue; both
+operands in the same bit-major column order), then
 runs the 16 plane-pair contractions
 
     acc[m, n] += Σ_{j,k} s_jk · 2^{j+k} · (xbits_j @ wbits_k^T)
@@ -33,11 +34,11 @@ Two kernels share this file:
   ``dot_general`` calls per tile (one MXU dispatch per pair).
 * :func:`bsdp_gemm_fused` — the single-contraction form (the paper's §IV
   "one dense pass instead of many scalar ones" restructuring, applied to
-  the MXU): the 4 planes are *interleaved into the row axis* of the bit
-  matrices, so one ``[bm·4, K] × [K, bn·4]`` contraction computes all 16
+  the MXU): the 4 planes are *stacked along the row axis* of the bit
+  matrices, so one ``[4·bm, K] × [K, 4·bn]`` contraction computes all 16
   plane-pair popcount sums at once, and the ``s_jk · 2^{j+k}`` weighting
-  collapses to a ``[4, 4]``-weighted elementwise reduce over the reshaped
-  ``[bm, 4, bn, 4]`` table — ONE MXU invocation per tile instead of 16.
+  collapses to a weighted sum of the table's 16 aligned ``[bm, bn]``
+  blocks — ONE MXU invocation per tile instead of 16.
   Bit-identical to :func:`bsdp_gemm` (asserted in tests and by the
   ``hlo_stats`` dot-count guard in ``tests/test_bsdp_gemm.py``).
 """
@@ -55,26 +56,26 @@ from repro.core.bsdp import plane_signs
 
 _WORD = 32
 
-
-def _plane_weights(signed: bool) -> jax.Array:
-    """``[4, 4]`` in-kernel constant ``s_jk · 2^{j+k}`` (int32).
-
-    Built from iota inside the kernel (Pallas kernels cannot capture traced
-    array constants): ``s_jk = -1`` iff exactly one of j, k == 3.
-    """
-    j = jax.lax.broadcasted_iota(jnp.int32, (4, 4), 0)
-    k = jax.lax.broadcasted_iota(jnp.int32, (4, 4), 1)
-    w = jnp.int32(1) << (j + k)
-    if signed:
-        w = jnp.where((j == 3) != (k == 3), -w, w)
-    return w
+#: Scoped-VMEM limit for both GEMM kernels.  A full-K word block at
+#: K = 6144 with 128-row tiles unpacks to ~18 MiB of bit matrices per step
+#: in the unrolled kernel, past the 16 MiB default; a TPU v5e core has
+#: 128 MiB of VMEM.
+_VMEM_LIMIT = 64 * 1024 * 1024
 
 
 def _unpack_bits(words: jax.Array) -> jax.Array:
-    """``[R, Kw] uint32 → [R, Kw*32] 0/1 int8`` (bit b of word w at w*32+b)."""
-    shifts = jnp.arange(_WORD, dtype=jnp.uint32)
-    bits = ((words[..., None] >> shifts) & jnp.uint32(1)).astype(jnp.int8)
-    return bits.reshape(words.shape[0], words.shape[1] * _WORD)
+    """``[R, Kw] uint32 → [R, 32·Kw] 0/1 int8``, bit-major: bit ``b`` of
+    word ``w`` lands in column ``b·Kw + w``.
+
+    Any column order gives the same contraction as long as both operands
+    share it; bit-major is the one Mosaic lowers, since each bit slice is a
+    whole ``[R, Kw]`` tile concatenated along lanes (the word-major
+    ``[R, Kw, 32] → [R, Kw·32]`` reshape is a shape cast it refuses)."""
+    return jnp.concatenate(
+        [((words >> b) & jnp.uint32(1)).astype(jnp.int8)
+         for b in range(_WORD)],
+        axis=1,
+    )
 
 
 def _bsdp_gemm_kernel(x_ref, w_ref, o_ref, acc_ref, *, signed: bool):
@@ -108,17 +109,16 @@ def _bsdp_gemm_kernel(x_ref, w_ref, o_ref, acc_ref, *, signed: bool):
         o_ref[...] = acc_ref[...]
 
 
-def _unpack_planes_rows(planes: jax.Array) -> jax.Array:
-    """``[R, 4, Kw] uint32 → [R·4, Kw·32] 0/1 int8`` — plane-interleaved rows.
+def _unpack_planes_stacked(planes: jax.Array) -> jax.Array:
+    """``[R, 4, Kw] uint32 → [4·R, 32·Kw] 0/1 int8`` — plane-major rows.
 
-    Row ``r·4 + j`` holds the ``2^j`` bit-plane of input row ``r``, so a
-    single contraction of two such matrices yields every (j, k) plane-pair
-    popcount sum as one entry of a ``[R·4, C·4]`` table.
+    Row ``j·R + r`` holds the ``2^j`` bit-plane of input row ``r`` (columns
+    in :func:`_unpack_bits` order), so a single contraction of two such
+    matrices yields every (j, k) plane-pair popcount sum, the pair (j, k)
+    as the aligned ``[R, C]`` block ``(j, k)`` of a ``[4·R, 4·C]`` table.
     """
-    r, p, kw = planes.shape
-    shifts = jnp.arange(_WORD, dtype=jnp.uint32)
-    bits = ((planes[..., None] >> shifts) & jnp.uint32(1)).astype(jnp.int8)
-    return bits.reshape(r * p, kw * _WORD)
+    return _unpack_bits(
+        jnp.concatenate([planes[:, j, :] for j in range(4)], axis=0))
 
 
 def _bsdp_gemm_fused_kernel(x_ref, w_ref, o_ref, acc_ref, *, signed: bool):
@@ -131,26 +131,28 @@ def _bsdp_gemm_fused_kernel(x_ref, w_ref, o_ref, acc_ref, *, signed: bool):
     x = x_ref[...]  # [bm, 4, bkw] uint32
     w = w_ref[...]  # [bn, 4, bkw] uint32
     bm, bn = x.shape[0], w.shape[0]
-    # Interleave planes into the row axis: [bm·4, K] and [bn·4, K] 0/1 bit
+    # Stack the planes along the row axis: [4·bm, K] and [4·bn, K] 0/1 bit
     # matrices — the fused operand layout.
-    xbits = _unpack_planes_rows(x)  # [bm*4, bkw*32]
-    wbits = _unpack_planes_rows(w)  # [bn*4, bkw*32]
+    xbits = _unpack_planes_stacked(x)  # [4*bm, bkw*32]
+    wbits = _unpack_planes_stacked(w)  # [4*bn, bkw*32]
     # ONE MXU contraction computes all 16 plane-pair popcount sums:
-    # table[m*4+j, n*4+k] == popcount(x_plane_j[m] AND w_plane_k[n]).
+    # table[j*bm+m, k*bn+n] == popcount(x_plane_j[m] AND w_plane_k[n]).
     table = jax.lax.dot_general(
         xbits,
         wbits,
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.int32,
-    )  # [bm*4, bn*4]
-    # Fold the s_jk·2^{j+k} shift/sign weighting as a [4,4]-weighted reduce
-    # over the reshaped [bm, 4, bn, 4] table (elementwise VPU epilogue — no
-    # further MXU work).
-    weights = _plane_weights(signed)  # [4, 4] int32
-    table = table.reshape(bm, 4, bn, 4)
-    acc_ref[...] = acc_ref[...] + jnp.sum(
-        table * weights[:, None, :], axis=(1, 3)
-    )
+    )  # [4*bm, 4*bn]
+    # Fold the s_jk·2^{j+k} shift/sign weighting over the 16 aligned
+    # [bm, bn] blocks of the table (elementwise VPU epilogue — no further
+    # MXU work).
+    signs = plane_signs(signed)
+    acc = acc_ref[...]
+    for j in range(4):
+        for k in range(4):
+            block = table[j * bm:(j + 1) * bm, k * bn:(k + 1) * bn]
+            acc = acc + block * (signs[j][k] * (1 << (j + k)))
+    acc_ref[...] = acc
 
     @pl.when(k_step == pl.num_programs(2) - 1)
     def _finalize():
@@ -166,23 +168,23 @@ def bsdp_gemm_fused(
     *,
     bm: int = 128,
     bn: int = 128,
-    bkw: int = 32,
+    bkw: int = 256,
     signed: bool = True,
     interpret: bool = False,
 ) -> jax.Array:
     """Fused single-contraction BSDP GEMM: ``[M,4,Kw] × [N,4,Kw] → [M,N]``.
 
     Same contract as :func:`bsdp_gemm`, but each grid step runs ONE
-    ``[bm·4, bkw·32] × [bkw·32, bn·4]`` int8 contraction (the plane axis
-    interleaved into the row axis) instead of 16 per-(j,k) matmuls, then
-    reduces the ``[bm, 4, bn, 4]`` plane-pair table with the ``[4, 4]``
-    ``s_jk·2^{j+k}`` weight matrix.  Bit-identical output; 1/16th the MXU
-    dispatches (asserted via ``hlo_stats`` dot counting in the tests).
+    ``[4·bm, bkw·32] × [bkw·32, 4·bn]`` int8 contraction (the plane axis
+    stacked along the row axis) instead of 16 per-(j,k) matmuls, then
+    folds the ``s_jk·2^{j+k}`` weights over the 16 ``[bm, bn]`` blocks of
+    the plane-pair table.  Bit-identical output; 1/16th the MXU dispatches
+    (asserted via ``hlo_stats`` dot counting in the tests).
 
-    VMEM at the ``(128, 128, 32)`` default: two 512×1024 int8 bit matrices
-    (1 MB), a 512×512 int32 pair table (1 MB) and the 64 KB accumulator —
-    comfortably inside a TPU core's VMEM, with an MXU-shaped
-    ``[512, 1024] × [1024, 512]`` contraction per step.
+    ``bkw`` must be a multiple of 128 or the whole word axis (the TPU
+    block rule for a minor dim).  At ``(128, 128, 192)`` (K = 6144): two
+    512×6144 int8 bit matrices (6 MiB), a 512×512 int32 pair table (1 MiB)
+    and the 64 KiB accumulator.
     """
     m, px, kw = x_planes.shape
     n, pw, kw2 = w_planes.shape
@@ -204,6 +206,7 @@ def bsdp_gemm_fused(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(x_planes, w_planes)
 
@@ -217,17 +220,17 @@ def bsdp_gemm(
     *,
     bm: int = 128,
     bn: int = 128,
-    bkw: int = 32,
+    bkw: int = 256,
     signed: bool = True,
     interpret: bool = False,
 ) -> jax.Array:
     """``x_planes [M,4,Kw] × w_planes [N,4,Kw] → [M,N] int32`` (exact).
 
-    Defaults: ``bkw=32`` words = 1024 int4 elements per K step.  A
-    ``(128, 128, 32)`` step stages 128·4·32·4B × 2 = 128 KB of planes and
-    unpacks them to 8 × 128×1024 int8 bit matrices (1 MB VMEM transient) —
-    well inside budget, with MXU-shaped ``[128, 1024] × [1024, 128]``
-    contractions per plane pair.
+    ``bkw`` must be a multiple of 128 or the whole word axis (the TPU
+    block rule for a minor dim).  A ``(128, 128, 192)`` step (K = 6144)
+    stages 128·4·192·4B × 2 = 768 KiB of planes and unpacks them to
+    8 × 128×6144 int8 bit matrices (6 MiB VMEM transient), with
+    ``[128, 6144] × [6144, 128]`` contractions per plane pair.
     """
     m, px, kw = x_planes.shape
     n, pw, kw2 = w_planes.shape
@@ -249,5 +252,6 @@ def bsdp_gemm(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(x_planes, w_planes)

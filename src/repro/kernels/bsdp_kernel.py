@@ -70,16 +70,17 @@ def bsdp_matmul(
     *,
     bm: int = 8,
     bn: int = 128,
-    bkw: int = 64,
+    bkw: int = 256,
     signed: bool = True,
     interpret: bool = False,
 ) -> jax.Array:
     """``x_planes [M,4,Kw] × w_planes [N,4,Kw] → [M,N] int32`` (exact).
 
-    Defaults: ``bkw=64`` words = 2048 int4 elements per tile; a
-    ``(8, 128, 64)`` step touches 8·4·64·4B + 128·4·64·4B = 139 KB of planes
-    and a 4 KB accumulator — comfortably inside the 128 KB/step VMEM budget
-    once double-buffered (Mosaic pipelines the next tile during compute).
+    ``bkw`` must be a multiple of 128 or the whole word axis (the TPU
+    block rule for a minor dim).  A ``(8, 128, 192)`` step (K = 6144)
+    touches 8·4·192·4B + 128·4·192·4B = 417 KB of planes and a 4 KB
+    accumulator; each plane pair's AND+popcount works on a
+    ``[8, 128, 192]`` uint32 transient (768 KiB).
     """
     m, px, kw = x_planes.shape
     n, pw, kw2 = w_planes.shape

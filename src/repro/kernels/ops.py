@@ -170,11 +170,14 @@ def quant_matmul_int4(
 #: per-kernel (preferred bm, bm align, preferred bkw) — bn is shared (128).
 #: This is the static FALLBACK table; autotuned winners (benchmarks/
 #: autotune.py) are registered per (kernel, shape class) in _BSDP_TUNED and
-#: take precedence in :func:`bsdp_blocks_for`.
+#: take precedence in :func:`bsdp_blocks_for`.  A word block must be a
+#: multiple of 128 or the whole (padded) word axis on the TPU: bkw 256
+#: takes the whole axis up to K = 8192, which covers every projection of
+#: the configs here without padding the weights.
 _BSDP_BLOCKS = {
-    "gemv": (8, 8, 64),
-    "gemm": (128, 8, 32),
-    "gemm_fused": (128, 8, 32),
+    "gemv": (8, 8, 256),
+    "gemm": (128, 8, 256),
+    "gemm_fused": (128, 8, 256),
 }
 
 #: kernel name → (module, attr), resolved at call time so tests can
@@ -355,7 +358,7 @@ def bsdp_gemv(
 
 
 def plane_decode_attention(
-    q_planes: jax.Array,   # [R, G, 4, Fw] uint32
+    q: jax.Array,          # [R, G, Fw·32] int8, int4-valued
     q_scale: jax.Array,    # [R, G] f32
     k_planes: jax.Array,   # [R, L, 4, Fw] uint32
     k_scale: jax.Array,    # [R, L] f32
@@ -371,13 +374,20 @@ def plane_decode_attention(
 
     Wraps :func:`repro.kernels.plane_attn.plane_decode_attention`: the qk
     scores, masked softmax and av gather run in ONE Pallas pass per
-    (batch × kv-head) row, contracting directly on the stored planes with
-    all scales folded after the integer contraction.  The word-padded
-    feature axis is sliced back to ``feat`` here.
+    (batch × kv-head) row, contracting against the stored planes decoded in
+    VMEM, with all scales folded after the integer contraction.  Here the
+    planes move to the kernel's slot-minor ``[R, 4·Fw, L]`` layout and the
+    word-padded feature axis is sliced back to ``feat``.
     """
-    _note_dispatch("plane_attn", k_planes.shape[1], feat)
+    r, l, _, fw = k_planes.shape
+
+    def words(planes):
+        return planes.reshape(r, l, 4 * fw).transpose(0, 2, 1)
+
+    _note_dispatch("plane_attn", l, feat)
     out = plane_attn.plane_decode_attention(
-        q_planes, q_scale, k_planes, k_scale, v_planes, v_scale, bias,
+        q, q_scale[..., None], words(k_planes), k_scale[:, None, :],
+        words(v_planes), v_scale[:, None, :], bias,
         sm_scale=sm_scale, interpret=_interpret(interpret),
     )
     return out[..., :feat]

@@ -104,6 +104,39 @@ def dequant_matmul_ref(
     return jnp.dot(x_bf16.astype(jnp.float32), w)
 
 
+def plane_decode_attention_ref(
+    q: jax.Array,
+    q_scale: jax.Array,
+    k_planes: jax.Array,
+    k_scale: jax.Array,
+    v_planes: jax.Array,
+    v_scale: jax.Array,
+    bias: jax.Array,
+    *,
+    sm_scale: float,
+    feat: int,
+    signed: bool = True,
+) -> jax.Array:
+    """Fused bit-plane decode-attention oracle: decode, then attend.
+
+    ``q [R, G, F]`` int4 values, ``k/v_planes [R, L, 4, F/32]``, per-row
+    scales ``[R, G]`` / ``[R, L]``, additive ``bias [R, G, L]`` →
+    ``[R, G, feat]`` f32.  Scores are exact int32 dot products; the value
+    read runs at highest matmul precision.
+    """
+    k = bitplane.decode(k_planes, signed=signed)  # [R, L, F] int8
+    v = bitplane.decode(v_planes, signed=signed).astype(jnp.float32)
+    s_int = jnp.einsum("rgf,rlf->rgl", q.astype(jnp.int32),
+                       k.astype(jnp.int32),
+                       preferred_element_type=jnp.int32)
+    scores = (s_int.astype(jnp.float32) * q_scale[..., None]
+              * k_scale[:, None, :] * sm_scale + bias)
+    w = jax.nn.softmax(scores, axis=-1) * v_scale[:, None, :]
+    out = jnp.einsum("rgl,rlf->rgf", w, v,
+                     precision=jax.lax.Precision.HIGHEST)
+    return out[..., :feat]
+
+
 def decode_weights_ref(w_planes: jax.Array, *, signed: bool = True) -> jax.Array:
     """[N, 4, Kw] planes → [K, N] int8 — layout round-trip oracle."""
     return bitplane.decode(w_planes, signed=signed).T
@@ -118,5 +151,6 @@ __all__ = [
     "bsdp_gemm_ref",
     "dim_w16a8_ref",
     "dequant_matmul_ref",
+    "plane_decode_attention_ref",
     "decode_weights_ref",
 ]

@@ -1,10 +1,3 @@
-import os
-
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=512 "
-    + os.environ.get("REPRO_EXTRA_XLA_FLAGS", "")
-)
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 For each cell this driver:
@@ -42,6 +35,7 @@ Usage:
 import argparse
 import dataclasses
 import json
+import os
 import time
 import traceback
 from typing import Optional
@@ -54,13 +48,7 @@ from repro.configs import SHAPES, applicable_shapes, get_config
 from repro.configs.base import ModelConfig, ShapeCell
 from repro.core import kvcache, residency
 from repro.launch import hlo_stats
-from repro.launch.mesh import (
-    cost_analysis,
-    jit_shardings,
-    make_production_mesh,
-    plan,
-    set_mesh,
-)
+from repro.launch.mesh import make_mesh, make_production_mesh, plan
 from repro.models import model as model_lib
 from repro.models.attention import attn_dims
 from repro.optim import adamw as optim_lib
@@ -514,7 +502,7 @@ def lower_cell(
         cfg = dataclasses.replace(cfg, moe_impl=moe_impl)
     cell = SHAPES[shape]
     if mesh_shape is not None:
-        mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+        mesh = make_mesh(mesh_shape, ("data", "model"))
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     pl = plan(cfg, cell, mesh)
@@ -551,10 +539,10 @@ def lower_cell(
             ),
             mesh=mesh,
         )
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(
                 step,
-                in_shardings=jit_shardings(mesh, (params_sh, opt_sh, batch_sh)),
+                in_shardings=(params_sh, opt_sh, batch_sh),
                 donate_argnums=(0, 1),
             )
             lowered = jitted.lower(params_abs, opt_abs, batch_abs)
@@ -570,10 +558,10 @@ def lower_cell(
                 rules=rules, impl="jnp", probe=is_probe,
             )
 
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(
                 prefill_step,
-                in_shardings=jit_shardings(mesh, (params_sh, batch_sh)),
+                in_shardings=(params_sh, batch_sh),
             )
             lowered = jitted.lower(params_abs, batch_abs)
             compiled = lowered.compile()
@@ -598,10 +586,10 @@ def lower_cell(
                 impl="jnp", probe=is_probe,
             )
 
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(
                 serve_step,
-                in_shardings=jit_shardings(mesh, (params_sh, tok_sh, cache_sh, pos_sh)),
+                in_shardings=(params_sh, tok_sh, cache_sh, pos_sh),
                 donate_argnums=(2,),
             )
             lowered = jitted.lower(params_abs, tok_abs, cache_abs, pos_abs)
@@ -610,7 +598,7 @@ def lower_cell(
     lower_s = time.time() - t0
     if print_analyses:
         print(compiled.memory_analysis())
-        print({k: v for k, v in cost_analysis(compiled).items()
+        print({k: v for k, v in compiled.cost_analysis().items()
                if k in ("flops", "bytes accessed")})
     return _collect(
         compiled, mesh=mesh, arch=arch, shape=shape, multi_pod=multi_pod,
@@ -621,7 +609,7 @@ def lower_cell(
 
 
 def _collect(compiled, *, mesh, **meta) -> dict:
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     mem = compiled.memory_analysis()
     coll = hlo_stats.collective_stats(compiled.as_text())
     mem_stats = {
@@ -755,7 +743,19 @@ def _registry_arg(parse):
     return convert
 
 
+def force_host_devices() -> None:
+    """Give the CPU backend the 512 devices the production meshes need.
+
+    Entry points call this before anything starts the JAX backend;
+    importing this module changes nothing."""
+    os.environ["XLA_FLAGS"] = (
+        "--xla_force_host_platform_device_count=512 "
+        + os.environ.get("REPRO_EXTRA_XLA_FLAGS", "")
+    )
+
+
 def main():
+    force_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

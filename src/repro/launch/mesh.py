@@ -20,63 +20,33 @@ import dataclasses
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.configs.base import ModelConfig, ShapeCell
 from repro.models.attention import attn_dims
 from repro.sharding import partitioning as P
 
 
-def set_mesh(mesh: Mesh):
-    """``jax.set_mesh`` compat shim.
+def make_mesh(shape, axes, *, devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``.
 
-    JAX ≥ 0.5 exposes ``jax.set_mesh(mesh)`` as the mesh-entering context
-    manager; older versions use the classic ``with mesh:`` context instead
-    (pair with :func:`jit_shardings` there, since bare PartitionSpecs are
-    not accepted by ``jax.jit``).  Always enter the returned object with
-    ``with``.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def jit_shardings(mesh: Mesh, tree):
-    """Make an ``in_shardings``/``out_shardings`` tree version-portable.
-
-    Under ``jax.set_mesh`` (JAX ≥ 0.5) bare :class:`PartitionSpec` leaves
-    resolve against the ambient mesh, so the tree passes through untouched.
-    Older ``jax.jit`` only accepts :class:`Sharding` objects — wrap every
-    PartitionSpec leaf in a :class:`NamedSharding` over ``mesh``.
-    """
-    if hasattr(jax, "set_mesh"):
-        return tree
-    from jax.sharding import NamedSharding, PartitionSpec
-
-    return jax.tree_util.tree_map(
-        lambda s: NamedSharding(mesh, s) if isinstance(s, PartitionSpec) else s,
-        tree,
-        is_leaf=lambda x: isinstance(x, PartitionSpec),
-    )
-
-
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` compat: older JAX returns a per-device
-    LIST of dicts, newer JAX one dict.  Always return one dict (device 0)."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
+    The models shard through PartitionSpec rule tables and
+    ``with_sharding_constraint`` and leave everything else to the
+    compiler's sharding propagation, which is what ``Auto`` axes mean
+    (``jax.make_mesh`` defaults to ``Explicit`` axes, under which sharded
+    gathers need an ``out_sharding`` at every call site)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_pp_mesh(*, stages: int = 2, data: int = 16, model: int = 16) -> Mesh:
-    return jax.make_mesh((stages, data, model), ("pipe", "data", "model"))
+    return make_mesh((stages, data, model), ("pipe", "data", "model"))
 
 
 @dataclasses.dataclass(frozen=True)

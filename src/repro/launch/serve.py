@@ -42,6 +42,7 @@ from repro import obs
 from repro.checkpoint import ckpt as ckpt_lib
 from repro.configs import ARCH_NAMES, get_config, get_smoke_config
 from repro.core import kvcache, residency
+from repro.launch import compile_cache
 from repro.models import model as model_lib
 from repro.serve import engine
 from repro.serve import scheduler as sched_lib
@@ -104,6 +105,7 @@ def main():
                     help="print one serving stats line to stderr every N "
                          "engine steps (0 = off)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     trace_sink = None
     if args.trace:

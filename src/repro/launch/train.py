@@ -13,11 +13,10 @@ from __future__ import annotations
 
 import argparse
 
-import jax
-
 from repro.configs import ARCH_NAMES, get_config, get_smoke_config
 from repro.data.pipeline import DataConfig
-from repro.launch.mesh import plan
+from repro.launch import compile_cache
+from repro.launch.mesh import make_mesh, plan
 from repro.configs.base import ShapeCell
 from repro.sharding import partitioning as P
 from repro.train.trainer import Trainer, TrainerConfig
@@ -41,6 +40,7 @@ def main():
                     help="data-parallel ways (0 = single device)")
     ap.add_argument("--model", type=int, default=1, help="TP ways")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     data = DataConfig(
@@ -51,7 +51,7 @@ def main():
     mesh = rules = None
     tp = args.model
     if args.data:
-        mesh = jax.make_mesh((args.data, args.model), ("data", "model"))
+        mesh = make_mesh((args.data, args.model), ("data", "model"))
         cell = ShapeCell("cli", args.seq_len, args.global_batch, "train")
         rules = plan(cfg, cell, mesh).rules
 

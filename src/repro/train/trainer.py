@@ -11,6 +11,7 @@ CPU and the multi-pod dry-run lowers at full scale.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Any, Callable, Optional
@@ -66,21 +67,37 @@ class Trainer:
             step_cfg=TrainStepConfig(microbatches=tcfg.microbatches),
             mesh=mesh,
         )
-        if mesh is None:
-            self.step_fn = jax.jit(self.step_fn, donate_argnums=(0, 1))
-        else:
-            self.step_fn = jax.jit(self.step_fn, donate_argnums=(0, 1))
+        self.step_fn = jax.jit(self.step_fn, donate_argnums=(0, 1))
         self.ckpt = ckpt_lib.AsyncCheckpointer()
         self.history: list[dict] = []
 
     # -- state ---------------------------------------------------------
 
     def init_state(self):
-        params = P.materialize(
-            model_lib.specs(self.cfg, self.tp), jax.random.PRNGKey(self.tcfg.seed)
-        )
-        opt_state = self.opt.init(params)
+        specs = model_lib.specs(self.cfg, self.tp)
+        key = jax.random.PRNGKey(self.tcfg.seed)
+        if self.mesh is None:
+            params = P.materialize(specs, key)
+            return params, self.opt.init(params), 0
+        # on a mesh, parameters and moments are created already sharded by
+        # the rule table: no device ever holds the whole unsharded model
+        from repro.launch.dryrun import opt_shardings
+
+        params = jax.jit(
+            lambda k: P.materialize(specs, k),
+            out_shardings=P.shardings(specs, self.mesh, self.rules),
+        )(key)
+        opt_state = jax.jit(
+            self.opt.init, out_shardings=opt_shardings(specs, self.rules)
+        )(params)
         return params, opt_state, 0
+
+    def _mesh_context(self):
+        """The mesh as ambient context, so the step's logical-axis
+        activation constraints resolve against it."""
+        if self.mesh is None:
+            return contextlib.nullcontext()
+        return jax.set_mesh(self.mesh)
 
     def restore_state(self):
         d = self.tcfg.ckpt_dir
@@ -105,6 +122,10 @@ class Trainer:
                 # loop re-enters, restoring from the latest checkpoint
 
     def _run_once(self) -> dict:
+        with self._mesh_context():
+            return self._loop()
+
+    def _loop(self) -> dict:
         params, opt_state, start = self.restore_state()
         src = SyntheticLM(self.data_cfg)
         t_tot0 = time.perf_counter()

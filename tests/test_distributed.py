@@ -12,7 +12,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 
 from repro.configs import get_smoke_config
 from repro.distributed import collectives, pipeline, resilience
-from repro.launch.mesh import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import model as model_lib
 from repro.sharding import partitioning as P
 
@@ -22,7 +22,7 @@ pytestmark = pytest.mark.skipif(
 
 
 def _mesh():
-    return jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    return make_mesh((2, 2, 2), ("pod", "data", "model"))
 
 
 class TestShardedTraining:
@@ -41,7 +41,7 @@ class TestShardedTraining:
         mesh = _mesh()
         rules = P.base_rules(fsdp=False, data_axes=("pod", "data"))
         spec_tree = model_lib.specs(cfg, tp=1)  # dims divisible by tp=2
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             params_sh = jax.device_put(params, P.shardings(spec_tree, mesh, rules))
             batch_sh = {
                 k: jax.device_put(
@@ -86,10 +86,9 @@ class TestCompressedCollectives:
         mesh = _mesh()
 
         from functools import partial
-        from jax.experimental.shard_map import shard_map
 
-        @partial(shard_map, mesh=mesh, in_specs=(PS("pod"),), out_specs=PS("pod"),
-                 check_rep=False)
+        @partial(jax.shard_map, mesh=mesh, in_specs=(PS("pod"),),
+                 out_specs=PS("pod"), check_vma=False)
         def run(v):
             return collectives.compressed_psum(v[0], "pod")[None]
 

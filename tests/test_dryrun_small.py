@@ -17,7 +17,7 @@ from jax.sharding import PartitionSpec as PS
 from repro.configs import get_smoke_config
 from repro.configs.base import ShapeCell
 from repro.launch import hlo_stats
-from repro.launch.mesh import cost_analysis, jit_shardings, plan, set_mesh
+from repro.launch.mesh import make_mesh, plan
 from repro.models import model as model_lib
 from repro.optim import adamw as optim_lib
 from repro.sharding import partitioning as P
@@ -36,12 +36,12 @@ class TestHloStats:
         assert hlo_stats._shape_bytes("pred[]") == 1
 
     def test_known_allreduce_bytes(self):
-        mesh = jax.make_mesh((8,), ("data",))
-        with set_mesh(mesh):
+        mesh = make_mesh((8,), ("data",))
+        with jax.set_mesh(mesh):
             f = jax.jit(
                 lambda x: jnp.sum(x, axis=0),
-                in_shardings=jit_shardings(mesh, PS("data")),
-                out_shardings=jit_shardings(mesh, PS()),
+                in_shardings=PS("data"),
+                out_shardings=PS(),
             )
             comp = f.lower(jax.ShapeDtypeStruct((64, 32), jnp.float32)).compile()
         st = hlo_stats.collective_stats(comp.as_text())
@@ -70,7 +70,7 @@ class TestProbeDifferencing:
     def test_probe_model_matches_unrolled(self):
         """fixed + n·body from depth-1/2 probes == fully-unrolled flops."""
         cfg = _tiny_cfg()
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         cell = ShapeCell("t", 64, 8, "train")
         rules = plan(cfg, cell, mesh).rules
         tp = 2
@@ -87,16 +87,16 @@ class TestProbeDifferencing:
                 c, opt, tp=tp, rules=rules,
                 step_cfg=TrainStepConfig(microbatches=1, remat=True, probe=probe),
             )
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 comp = jax.jit(
                     step,
-                    in_shardings=jit_shardings(mesh, (
+                    in_shardings=(
                         P.pspecs(spec_tree, rules),
                         opt_shardings(spec_tree, rules),
                         batch_sh,
-                    )),
+                    ),
                 ).lower(params_abs, opt_abs, batch_abs).compile()
-            return float(cost_analysis(comp)["flops"])
+            return float(comp.cost_analysis()["flops"])
 
         f1 = lower_flops(dataclasses.replace(cfg, n_layers=1), probe=True)
         f2 = lower_flops(dataclasses.replace(cfg, n_layers=2), probe=True)
@@ -112,7 +112,7 @@ class TestProbeDifferencing:
         """Documents WHY probes exist: the scanned program reports ~1
         superblock of flops regardless of depth."""
         cfg = _tiny_cfg()
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         cell = ShapeCell("t", 64, 8, "train")
         rules = plan(cfg, cell, mesh).rules
         spec_tree = model_lib.specs(cfg, 2)
@@ -124,17 +124,17 @@ class TestProbeDifferencing:
             cfg, opt, tp=2, rules=rules,
             step_cfg=TrainStepConfig(microbatches=1, remat=True, probe=False),
         )
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             comp = jax.jit(
                 step,
-                in_shardings=jit_shardings(mesh, (
+                in_shardings=(
                     P.pspecs(spec_tree, rules),
                     opt_shardings(spec_tree, rules),
                     batch_sh,
-                )),
+                ),
             ).lower(P.abstract(spec_tree), opt.init_abstract(P.abstract(spec_tree)),
                     batch_abs).compile()
-        scanned = float(cost_analysis(comp)["flops"])
+        scanned = float(comp.cost_analysis()["flops"])
         # the 4-layer unrolled equivalent must be substantially larger
         # (scan body counted once)
         assert scanned > 0
@@ -149,7 +149,7 @@ class TestSmallMeshLowering:
         import repro.launch.dryrun as dr
 
         cfg = _tiny_cfg()
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         cell = ShapeCell("t", 64, 8, kind)
         rules = plan(cfg, cell, mesh).rules
         tp = 2
@@ -160,12 +160,11 @@ class TestSmallMeshLowering:
             params_abs = P.abstract(spec_tree)
             batch_abs, batch_sh = dr.batch_specs(cfg, cell, rules)
             step = make_train_step(cfg, opt, tp=tp, rules=rules)
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 comp = jax.jit(
                     step,
-                    in_shardings=jit_shardings(
-                        mesh, (P.pspecs(spec_tree, rules),
-                               dr.opt_shardings(spec_tree, rules), batch_sh)),
+                    in_shardings=(P.pspecs(spec_tree, rules),
+                                  dr.opt_shardings(spec_tree, rules), batch_sh),
                 ).lower(params_abs, opt.init_abstract(params_abs), batch_abs
                         ).compile()
         elif kind == "prefill":
@@ -176,9 +175,9 @@ class TestSmallMeshLowering:
                 return model_lib.prefill(p, b, cfg, tp=tp, max_len=64,
                                          rules=rules, impl="jnp")
 
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 comp = jax.jit(
-                    pf, in_shardings=jit_shardings(mesh, (params_sh, batch_sh))
+                    pf, in_shardings=(params_sh, batch_sh)
                 ).lower(
                     params_abs, batch_abs).compile()
         else:
@@ -194,17 +193,16 @@ class TestSmallMeshLowering:
                 return model_lib.decode_step(p, t, c, pos, cfg, tp=tp,
                                              rules=rules, impl="jnp")
 
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 comp = jax.jit(
                     ds,
-                    in_shardings=jit_shardings(
-                        mesh, (params_sh, PS(("pod", "data")), cache_sh,
-                               PS(("pod", "data")))),
+                    in_shardings=(params_sh, PS(("pod", "data")), cache_sh,
+                                  PS(("pod", "data"))),
                 ).lower(
                     params_abs,
                     jax.ShapeDtypeStruct((8, 1), jnp.int32),
                     cache_abs,
                     jax.ShapeDtypeStruct((8,), jnp.int32),
                 ).compile()
-        assert cost_analysis(comp)["flops"] > 0
+        assert comp.cost_analysis()["flops"] > 0
         assert comp.memory_analysis() is not None

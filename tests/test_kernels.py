@@ -120,6 +120,46 @@ class TestBsdpKernel:
             assert bool(jnp.all(out == base)), (bm, bn, bkw)
 
 
+def plane_attention_inputs(rng, r, g, l, feat):
+    """Random operands of ``ops.plane_decode_attention``: int4 queries,
+    encoded K/V planes, positive scales and a mask with dead slots."""
+    fw = -(-feat // 32)
+    q = rng.integers(-8, 8, size=(r, g, feat)).astype(np.int8)
+    q = np.pad(q, ((0, 0), (0, 0), (0, fw * 32 - feat)))
+
+    def planes():
+        vals = rng.integers(-8, 8, size=(r, l, feat)).astype(np.int8)
+        return bitplane.encode(bitplane.pad_to_word(jnp.asarray(vals)))
+
+    bias = np.where(rng.random((r, g, l)) < 0.2, -1e30, 0.0)
+    bias[..., 0] = 0.0  # at least one live slot per query
+    return dict(
+        q=jnp.asarray(q),
+        q_scale=jnp.asarray(rng.uniform(0.1, 1.0, (r, g)), jnp.float32),
+        k_planes=planes(),
+        k_scale=jnp.asarray(rng.uniform(0.1, 1.0, (r, l)), jnp.float32),
+        v_planes=planes(),
+        v_scale=jnp.asarray(rng.uniform(0.1, 1.0, (r, l)), jnp.float32),
+        bias=jnp.asarray(bias, jnp.float32),
+    )
+
+
+class TestPlaneAttention:
+    @pytest.mark.parametrize("r,g,l,feat", [
+        (2, 2, 8, 16),       # one word, word-pad slack
+        (4, 6, 40, 128),     # full head dim, ragged ring
+        (3, 64, 16, 96),     # a 32-token chunk of group-2 queries
+    ])
+    def test_matches_oracle(self, r, g, l, feat):
+        rng = np.random.default_rng(r * 100 + g + l + feat)
+        args = plane_attention_inputs(rng, r, g, l, feat)
+        out = ops.plane_decode_attention(**args, sm_scale=0.125, feat=feat)
+        exp = ref.plane_decode_attention_ref(**args, sm_scale=0.125, feat=feat)
+        assert out.shape == (r, g, feat)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(exp), rtol=1e-4, atol=1e-4)
+
+
 class TestDimKernel:
     @pytest.mark.parametrize("m,k,n", [(1, 128, 128), (4, 96, 130), (16, 512, 256)])
     def test_exact_full_range(self, m, k, n):

@@ -359,12 +359,12 @@ class TestMixedResidency:
         import dataclasses
 
         from repro.launch import dryrun
-        from repro.launch.mesh import set_mesh
+        from repro.launch.mesh import make_mesh
         from repro.models.attention import attn_dims
 
         if jax.device_count() < 4:
             pytest.skip("needs 4 host devices")
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         tp = 2
         cfg = dataclasses.replace(
             get_smoke_config("qwen3-1.7b").scaled(n_layers=2),
@@ -400,15 +400,12 @@ class TestMixedResidency:
         cache_sh = P.cache_pspecs(cache_abs, rules, shard_kv, cfg)
         tok_abs = jax.ShapeDtypeStruct((b, 1), jnp.int32)
         pos_abs = jax.ShapeDtypeStruct((b,), jnp.int32)
-        from repro.launch.mesh import jit_shardings
-
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(
                 lambda p, t, c, pos: model_lib.decode_step(
                     p, t, c, pos, cfg, tp=tp, rules=rules, impl="jnp"),
-                in_shardings=jit_shardings(
-                    mesh, (params_sh, P.spec_for(("batch", None), rules),
-                           cache_sh, P.spec_for(("batch",), rules))),
+                in_shardings=(params_sh, P.spec_for(("batch", None), rules),
+                              cache_sh, P.spec_for(("batch",), rules)),
             )
             compiled = jitted.lower(
                 params_abs, tok_abs, cache_abs, pos_abs).compile()
