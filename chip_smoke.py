@@ -94,33 +94,20 @@ class Traffic:
 # ---------------------------------------------------------------------------
 
 
-class CompileClock:
-    """Seconds JAX spends compiling (cache retrievals included) and the
-    persistent-cache hits, read from JAX's monitoring events."""
+def run_phase(name, fn):
+    """Run one phase; print its wall seconds and the compile seconds and
+    persistent-cache hits that ``repro.obs`` counted during it."""
+    from repro.obs import counter_total
 
-    def __init__(self):
-        import jax
+    def compiled():
+        return (counter_total("jax.compile_s"),
+                counter_total("jax.compile_cache_hit"))
 
-        self.seconds, self.hits = 0.0, 0
-
-        def on_duration(event, duration, **_):
-            if event == "/jax/core/compile/backend_compile_duration":
-                self.seconds += duration
-
-        def on_event(event, **_):
-            if event == "/jax/compilation_cache/cache_hits":
-                self.hits += 1
-
-        jax.monitoring.register_event_duration_secs_listener(on_duration)
-        jax.monitoring.register_event_listener(on_event)
-
-
-def run_phase(name, fn, clock):
-    c0, h0, t0 = clock.seconds, clock.hits, time.perf_counter()
+    (c0, h0), t0 = compiled(), time.perf_counter()
     out = fn()
+    c1, h1 = compiled()
     print(f"phase {name}: ok  wall {time.perf_counter() - t0:.1f}s  "
-          f"compile {clock.seconds - c0:.1f}s  cache hits {clock.hits - h0}",
-          flush=True)
+          f"compile {c1 - c0:.1f}s  cache hits {h1 - h0:.0f}", flush=True)
     return out
 
 
@@ -447,43 +434,43 @@ def main(argv=None) -> int:
 
     sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                     "src"))
+    from repro import obs
     from repro.configs import get_config
     from repro.launch import compile_cache
     from repro.models import model as model_lib
     from repro.sharding import partitioning as P
 
     print(f"compilation cache: {compile_cache.enable()}", flush=True)
-    clock = CompileClock()
+    # a sink turns the obs compile counters on; the records are not kept
+    obs.register_sink(obs.NullSink())
     cfg = get_config(ARCH)
     t_start = time.perf_counter()
 
     if args.four_chips:
-        run_phase("four_chips", lambda: four_chips(cfg), clock)
+        run_phase("four_chips", lambda: four_chips(cfg))
     else:
         traffic = Traffic()
         params = run_phase(
             "init", lambda: jax.block_until_ready(P.materialize(
-                model_lib.specs(cfg, 1), jax.random.PRNGKey(SEED))),
-            clock)
+                model_lib.specs(cfg, 1), jax.random.PRNGKey(SEED))))
         ref_first = run_phase(
-            "reference", lambda: phase_reference(params, cfg, traffic),
-            clock)
-        run_phase("A", lambda: phase_a(params, cfg, traffic, ref_first),
-                  clock)
-        eng = run_phase("B", lambda: phase_b(params, cfg, traffic, ref_first),
-                        clock)
+            "reference", lambda: phase_reference(params, cfg, traffic))
+        run_phase("A", lambda: phase_a(params, cfg, traffic, ref_first))
+        eng = run_phase("B", lambda: phase_b(params, cfg, traffic, ref_first))
         check_mosaic_in_decode(eng)
         del eng
         cut_params, cut = first_layers(params, cfg, CHECK_LAYERS)
         run_phase(f"B at {CHECK_LAYERS} layers", lambda: phase_b(
             cut_params, cut, traffic,
-            phase_reference(cut_params, cut, traffic)), clock)
+            phase_reference(cut_params, cut, traffic)))
         del params, cut_params
-        run_phase("C", lambda: phase_c(cfg), clock)
+        run_phase("C", lambda: phase_c(cfg))
 
     total = time.perf_counter() - t_start
-    print(f"total: wall {total:.1f}s  compile {clock.seconds:.1f}s  "
-          f"cache hits {clock.hits}", flush=True)
+    print(f"total: wall {total:.1f}s  "
+          f"compile {obs.counter_total('jax.compile_s'):.1f}s  "
+          f"cache hits {obs.counter_total('jax.compile_cache_hit'):.0f}",
+          flush=True)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(devices)}}))

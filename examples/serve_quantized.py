@@ -32,8 +32,8 @@ resident concern:
 * observability (:mod:`repro.obs`, the fifth registry concept): the final
   all-fused row re-runs with ``trace=True`` and prints a timeline excerpt
   (the step loop decomposed into plan/prefill/decode spans) plus the
-  per-kernel dispatch table counted at trace time — the 16→1 fused-kernel
-  dispatch collapse as a measured serving artifact.
+  per-kernel table of call sites counted at trace time — the 16→1
+  fused-kernel collapse as a measured serving artifact.
 
 Each row reports throughput, resident weight bytes, cache bytes, p50 TTFT
 (in the engine's deterministic processed-position work units, from
@@ -120,7 +120,7 @@ def main():
 def _traced_excerpt(params, cfg, prompts, args):
     """Serve the all-fused pairing once more with tracing on and print what
     the observability registry saw: a span summary of the step loop and the
-    per-kernel dispatch table."""
+    per-kernel table of traced call sites."""
     import repro.obs as obs
 
     eng = engine.ServeEngine(
@@ -140,8 +140,8 @@ def _traced_excerpt(params, cfg, prompts, args):
     for name, s in sorted(obs.summarize_spans(timeline).items()):
         print(f"{name:<18} {s['count']:>5} {s['total_s']*1e3:>9.1f} "
               f"{s['p50_s']*1e3:>8.2f}")
-    print("kernel dispatches (trace-time call sites per compiled program):")
-    for key, count in sorted(obs.dispatch_table(timeline).items()):
+    print("kernel call sites per compiled program (counted at trace time):")
+    for key, count in sorted(obs.traced_kernels(timeline).items()):
         labels = ",".join(f"{k}={v}" for k, v in key)
         print(f"  {labels:<40} {count}")
 

@@ -105,9 +105,9 @@ def bsdp_matmul_planes(
     """
     from repro.obs import trace as obs
     if obs.active():
-        # same trace-time dispatch accounting as the Pallas wrappers in
+        # same trace-time call-site count as the Pallas wrappers in
         # kernels/ops.py — this is the jnp form of the fused contraction
-        obs.counter("kernel.dispatch", kernel="gemm_fused", impl="jnp")
+        obs.counter("kernel.traced", kernel="gemm_fused", impl="jnp")
     xb = _bits_to_int8(x_planes)  # [M, 4, K] 0/1 int8
     wb = _bits_to_int8(w_planes)  # [N, 4, K] 0/1 int8
     # One fused contraction over K: [M,4,N,4] popcount table.

@@ -420,7 +420,7 @@ class BitPlaneCacheFormat(CacheFormat):
         ``planes_gemm_fused``)."""
         from repro.obs import trace as obs  # deferred: kvcache loads early
         if obs.active():
-            obs.counter("kernel.dispatch", kernel=kernel, fmt=self.name)
+            obs.counter("kernel.traced", kernel=kernel, fmt=self.name)
         if kernel == "popcount":
             return bsdp.bsdp_popcount(
                 q_planes[..., :, None, :, :], k_planes[..., None, :, :, :],

@@ -14,13 +14,12 @@ These handle everything the raw kernels require of their callers:
 Every wrapper has a matching oracle in :mod:`repro.kernels.ref` and a
 shape/dtype sweep test in ``tests/test_kernels_*.py``.
 
-Each wrapper also emits a ``kernel.dispatch`` counter (:mod:`repro.obs`)
+Each wrapper also emits a ``kernel.traced`` counter (:mod:`repro.obs`)
 labelled with the kernel name and resolved block shape.  Because the
 wrappers run under ``jax.jit``, the counter fires at **trace time**: it
-counts kernel *call sites per compiled program*, not per-step executions —
-which is precisely the dispatch-cost artifact of the fused-kernel story
-(one compilation of the unrolled BSDP GEMM records 16 plane-pair
-dispatches where ``gemm_fused`` records 1).
+counts kernel *call sites per compiled program*, not per-step executions
+(one compilation of the unrolled BSDP GEMM records 16 plane-pair call
+sites where ``gemm_fused`` records 1).
 """
 
 from __future__ import annotations
@@ -71,10 +70,10 @@ def _pad2(x: jax.Array, m: int, n: int) -> jax.Array:
     return jnp.pad(x, ((0, pm), (0, pn)))
 
 
-def _note_dispatch(kernel: str, *blocks: int) -> None:
+def _note_traced(kernel: str, *blocks: int) -> None:
     """Count one kernel call site (trace-time under jit; see module doc)."""
     if obs.active():
-        obs.counter("kernel.dispatch", kernel=kernel,
+        obs.counter("kernel.traced", kernel=kernel,
                     blocks="x".join(str(b) for b in blocks))
 
 
@@ -105,7 +104,7 @@ def quant_matmul(
     wd = _pad2(w.data, kp, np_)
     xs = _pad2(x.scale.reshape(m, 1), mp, 1)
     ws = _pad2(w.scale.reshape(1, n), 1, np_)
-    _note_dispatch("int8", bm, bn, bk)
+    _note_traced("int8", bm, bn, bk)
     out = gemv_int8.matmul_int8(
         xd, wd, xs, ws, bm=bm, bn=bn, bk=bk,
         interpret=_interpret(interpret), out_int32=out_int32,
@@ -156,7 +155,7 @@ def quant_matmul_int4(
     wd = _pad2(w_packed, kp // 2, np_)
     xs = _pad2(x.scale.reshape(m, 1), mp, 1)
     ws = _pad2(w_scale.reshape(1, n), 1, np_)
-    _note_dispatch("int4_packed", bm, bn, bk)
+    _note_traced("int4_packed", bm, bn, bk)
     out = gemv_int4.matmul_int4_packed(
         xd, wd, xs, ws, bm=bm, bn=bn, bk=bk, interpret=_interpret(interpret)
     )
@@ -313,7 +312,7 @@ def bsdp_matmul_planes(
     def pad3(p, d0, d2):
         return jnp.pad(p, ((0, d0 - p.shape[0]), (0, 0), (0, d2 - p.shape[2])))
 
-    _note_dispatch(kernel, bm, bn, bkw)
+    _note_traced(kernel, bm, bn, bkw)
     mod, attr = _BSDP_KERNEL_IMPLS[kernel]
     fn = getattr(mod, attr)
     out = fn(
@@ -384,7 +383,7 @@ def plane_decode_attention(
     def words(planes):
         return planes.reshape(r, l, 4 * fw).transpose(0, 2, 1)
 
-    _note_dispatch("plane_attn", l, feat)
+    _note_traced("plane_attn", l, feat)
     out = plane_attn.plane_decode_attention(
         q, q_scale[..., None], words(k_planes), k_scale[:, None, :],
         words(v_planes), v_scale[:, None, :], bias,
@@ -415,7 +414,7 @@ def dim_matmul(
     bn = bn or _pick_block(n, 128, 128)
     bk = bk or _pick_block(k, 256, 128)
     mp, np_, kp = _round_up(m, bm), _round_up(n, bn), _round_up(k, bk)
-    _note_dispatch("w16a8_dim", bm, bn, bk)
+    _note_traced("w16a8_dim", bm, bn, bk)
     out = dim_kernel.matmul_w16a8(
         _pad2(x_i8, mp, kp),
         _pad2(w_i16, kp, np_),
@@ -446,7 +445,7 @@ def weight_only_matmul(
     bn = bn or _pick_block(n, 128, 128)
     bk = bk or _pick_block(k, 512, 128)
     mp, np_, kp = _round_up(m, bm), _round_up(n, bn), _round_up(k, bk)
-    _note_dispatch("w8a16_dequant", bm, bn, bk)
+    _note_traced("w8a16_dequant", bm, bn, bk)
     out = dequant_gemv.dequant_matmul(
         _pad2(x, mp, kp),
         _pad2(w.data, kp, np_),

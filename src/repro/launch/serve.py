@@ -24,10 +24,13 @@ fails fast with the registered list:
 
 Observability (:mod:`repro.obs`, the fifth registry concept) wires in via
 ``--trace out.json`` (Chrome-trace/Perfetto export of the whole run:
-step-loop spans, kernel dispatch counters, page-pool gauges, request
-lifecycle instants — load it at https://ui.perfetto.dev, or validate with
-``python -m repro.obs.validate out.json``) and ``--stats-every N`` (one
-serving stats line to stderr every N engine steps).
+step-loop spans, the trace-time ``kernel.traced`` call-site counters,
+``jax.compile`` counts labelled by the span that compiled, page-pool
+gauges, request lifecycle instants — load it at https://ui.perfetto.dev,
+or validate with ``python -m repro.obs.validate out.json``) and
+``--stats-every N`` (one serving stats line to stderr every N engine
+steps: tokens, compiles and their seconds, pages, cache bytes, step
+time).
 """
 
 from __future__ import annotations

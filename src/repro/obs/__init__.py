@@ -11,10 +11,10 @@ from repro.obs.export import ChromeTraceSink, chrome_trace, write_chrome_trace
 from repro.obs.metrics import (
     StatsLineSink,
     counter_total,
-    dispatch_table,
     percentile,
     request_stats_from_events,
     summarize_spans,
+    traced_kernels,
 )
 from repro.obs.trace import (
     NULL_SPAN,
@@ -61,7 +61,6 @@ __all__ = [
     "counters_snapshot",
     "current_depth",
     "disabled",
-    "dispatch_table",
     "event",
     "gauge",
     "gauge_value",
@@ -73,6 +72,7 @@ __all__ = [
     "sinks",
     "span",
     "summarize_spans",
+    "traced_kernels",
     "unregister_sink",
     "validate_chrome",
     "write_chrome_trace",

@@ -11,9 +11,10 @@ are the summaries the launchers and examples print:
                                against ``np.percentile``.
 * :func:`summarize_spans`    — per-span-name duration stats (count / total
                                / p50 / p95 / max).
-* :func:`dispatch_table`     — kernel-dispatch counts per (kernel, labels)
-                               series from ``kernel.dispatch`` counter
-                               records (each record is one dispatch).
+* :func:`traced_kernels`     — kernel call sites per (kernel, labels)
+                               series from ``kernel.traced`` counter
+                               records (each record is one call site in
+                               one traced program, not one execution).
 * :func:`request_stats_from_events` — rebuild per-request
                                :class:`~repro.serve.scheduler.RequestStats`
                                from the ``request.*`` lifecycle event
@@ -79,14 +80,16 @@ def summarize_spans(records) -> dict[str, dict]:
     return out
 
 
-def dispatch_table(records, name: str = "kernel.dispatch") -> dict[tuple, int]:
-    """Kernel-dispatch counts per label series from one timeline.
+def traced_kernels(records, name: str = "kernel.traced") -> dict[tuple, int]:
+    """Kernel call sites per label series from one timeline.
 
-    Every ``kernel.dispatch`` counter record is one dispatch (the counters
-    increment by 1), so counting records — rather than reading the global
-    running totals, which other engines in the process also bump — gives
-    the per-timeline table: ``{(("kernel","gemm_fused"), ("blocks","128x128x32"),
-    ...): n_calls}`` keyed by the sorted label items.
+    The ``kernel.traced`` counters fire when a kernel wrapper is traced, so
+    under ``jax.jit`` one record is one call site of one compiled program,
+    however often that program runs.  Counting records — rather than
+    reading the global running totals, which other engines in the process
+    also bump — gives the per-timeline table: ``{(("kernel","gemm_fused"),
+    ("blocks","128x128x32"), ...): n_sites}`` keyed by the sorted label
+    items.
     """
     table: dict[tuple, int] = {}
     for r in records:
@@ -109,6 +112,7 @@ def counter_total(name: str) -> float:
 
 #: lifecycle event names the engine emits (one mark per Stamp it takes)
 EV_ARRIVAL = "request.arrival"
+EV_SCHEDULED = "request.scheduled"
 EV_FIRST_TOKEN = "request.first_token"
 EV_FINISHED = "request.finished"
 
@@ -172,10 +176,11 @@ class StatsLineSink(Sink):
 
     Triggered by ``engine.step`` span records (the engine emits exactly one
     per :meth:`~repro.serve.engine.ServeEngine.step`); the line summarizes
-    the live registry — emitted tokens, kernel dispatches, page occupancy
-    and resident bytes — plus the mean step wall time over the window::
+    the live registry — emitted tokens, programs compiled (persistent-cache
+    retrievals included) and their seconds, page occupancy and resident
+    bytes — plus the median step wall time over the window::
 
-        [obs] step 40 | 128 tok (3.2 tok/step) | 212 dispatches | \
+        [obs] step 40 | 128 tok (3.2 tok/step) | 3 compiles (1.2s) | \
 pages 14/16 (hw 16) | cache 0.04 MB | step p50 12.1ms
 
     This is the ``launch/serve.py --stats-every N`` wiring; ``stream``
@@ -204,7 +209,8 @@ pages 14/16 (hw 16) | cache 0.04 MB | step p50 12.1ms
         parts = [
             f"[obs] step {self._steps}",
             f"{tokens:.0f} tok ({d_tok / self.every:.1f} tok/step)",
-            f"{counter_total('kernel.dispatch'):.0f} dispatches",
+            f"{counter_total('jax.compile'):.0f} compiles "
+            f"({counter_total('jax.compile_s'):.1f}s)",
         ]
         occ = _trace.gauge_value("pages.occupancy")
         if occ is not None:

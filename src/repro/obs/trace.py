@@ -7,11 +7,11 @@ what surfaces software-stack inefficiencies.  This module is that layer
 for the serving stack: the **fifth registry concept** after weights,
 caches, pages and schedulers.  Observability *sinks* are registered
 exactly like formats and schedulers (:func:`register_sink`), and every
-instrumented site — the engine step loop, the kernel dispatch wrappers,
+instrumented site — the engine step loop, the kernel wrappers,
 the page pool, the schedulers — talks to the registry instead of owning
 its own logging.
 
-Three primitives, all **zero-overhead when disabled** (no sink registered,
+Four primitives, all **zero-overhead when disabled** (no sink registered,
 or inside :func:`disabled`):
 
 ``span(name, **attrs)``    a ``with``-scoped timed region.  Disabled, it
@@ -22,24 +22,34 @@ or inside :func:`disabled`):
                            exit (exception-safe: the record is emitted and
                            the depth restored even when the body raises,
                            with the exception type stamped into ``attrs``).
+                           An enabled span also enters a
+                           ``jax.profiler.TraceAnnotation`` of its name, so
+                           a profiler trace shows it on the host thread
+                           that ran it, on the device trace's clock.
 ``counter(name, n, **lb)`` a monotonically accumulating metric, keyed by
                            ``(name, sorted labels)`` in a module registry;
                            each increment also emits a
                            :class:`PointRecord` carrying the running
                            total.  NOTE on jitted code: a ``counter()``
                            call inside a traced function runs at *trace*
-                           time, so kernel-dispatch counters count kernel
-                           call sites per compiled program — exactly the
-                           dispatch-cost artifact of the interpret-vs-TPU
-                           story (one compilation of the unrolled BSDP
-                           GEMM records 16 dispatches, the fused kernel 1).
+                           time, so the ``kernel.traced`` counters count
+                           kernel call sites per compiled program, not
+                           executions (one compilation of the unrolled
+                           BSDP GEMM records 16, the fused kernel 1).
 ``gauge(name, v, **lb)``   a last-value metric (pool occupancy, resident
                            bytes); same registry, same record stream.
 
 ``event(name, **lb)``      an instant (zero-duration) mark — the request
-                           lifecycle stream (arrival / first token /
-                           finished) that :mod:`repro.obs.metrics` turns
-                           back into TTFT/TPOT.
+                           lifecycle stream (arrival / scheduled / first
+                           token / finished) that :mod:`repro.obs.metrics`
+                           turns back into TTFT/TPOT.
+
+Registering the first sink also registers one process-wide
+``jax.monitoring`` listener: while tracing is active every compilation
+(persistent-cache retrievals included) counts as ``jax.compile`` (its
+seconds as ``jax.compile_s``) and every persistent-cache hit as
+``jax.compile_cache_hit``, each labelled ``span=`` the innermost open span
+(``"none"`` outside any), which names the step phase that compiled.
 
 Shipped sinks: :class:`NullSink` (explicit no-op), :class:`RingSink`
 (bounded in-memory ring — powers ``ServeEngine.timeline()``), the
@@ -139,12 +149,48 @@ class RingSink(Sink):
 
 _SINKS: list[Sink] = []
 _ENABLED = True
+#: ``jax.profiler``, imported when the first sink is registered
+_profiler = None
+
+#: jax.monitoring event names the compile listener counts
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+def _innermost() -> str:
+    return _stack[-1] if _stack else "none"
+
+
+def _on_compile(event: str, duration: float, **_) -> None:
+    if event == COMPILE_EVENT and _ENABLED and _SINKS:
+        where = _innermost()
+        counter("jax.compile", span=where)
+        counter("jax.compile_s", duration, span=where)
+
+
+def _on_cache_hit(event: str, **_) -> None:
+    if event == CACHE_HIT_EVENT and _ENABLED and _SINKS:
+        counter("jax.compile_cache_hit", span=_innermost())
+
+
+def _bind_jax() -> None:
+    """Import ``jax.profiler`` and register the compile listeners, once
+    per process."""
+    global _profiler
+    if _profiler is not None:
+        return
+    import jax
+
+    jax.monitoring.register_event_duration_secs_listener(_on_compile)
+    jax.monitoring.register_event_listener(_on_cache_hit)
+    _profiler = jax.profiler
 
 
 def register_sink(sink: Sink) -> Sink:
     """Register a sink; returns it (so ``ring = register_sink(RingSink())``
     reads naturally).  The first registered sink is what flips the
     module from the zero-overhead disabled path to recording."""
+    _bind_jax()
     _SINKS.append(sink)
     return sink
 
@@ -192,7 +238,8 @@ def disabled():
 # ---------------------------------------------------------------------------
 
 _clock: Callable[[], float] = time.perf_counter
-_depth = 0
+#: names of the open spans, outermost first (its length is the depth)
+_stack: list[str] = []
 
 
 class _NullSpan:
@@ -213,23 +260,24 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "attrs", "_t0", "_depth")
+    __slots__ = ("name", "attrs", "_t0", "_depth", "_annotation")
 
     def __init__(self, name: str, attrs: dict):
         self.name = name
         self.attrs = attrs
 
     def __enter__(self):
-        global _depth
-        self._depth = _depth
-        _depth += 1
+        self._depth = len(_stack)
+        _stack.append(self.name)
+        self._annotation = _profiler.TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self._t0 = _clock()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        global _depth
         dur = _clock() - self._t0
-        _depth = self._depth
+        self._annotation.__exit__(exc_type, exc, tb)
+        del _stack[self._depth:]
         if exc_type is not None:
             # exception-safe: the span still records, tagged with the error
             self.attrs = dict(self.attrs, error=exc_type.__name__)
@@ -243,7 +291,8 @@ def span(name: str, **attrs):
     """Timed region: ``with span("engine.prefill", slots=2, tokens=17):``.
 
     Disabled (no sinks / inside :func:`disabled`): returns the shared
-    :data:`NULL_SPAN` singleton — no allocation, no clock read.
+    :data:`NULL_SPAN` singleton — no allocation, no clock read, no
+    profiler annotation.
     """
     if not (_ENABLED and _SINKS):
         return NULL_SPAN
@@ -324,4 +373,4 @@ def reset_metrics() -> None:
 def current_depth() -> int:
     """Live span-nesting depth (0 outside any span) — invariant-checked by
     the nesting/exception-safety property tests."""
-    return _depth
+    return len(_stack)

@@ -44,9 +44,11 @@ CANCELLED``) with per-token streaming callbacks and three-clock SLO stamps
 
 The whole step loop is instrumented through :mod:`repro.obs` — the fifth
 registry concept: each ``step()`` decomposes into ``engine.plan`` /
-``reserve`` / ``cow`` / ``prefill`` / ``decode`` / ``complete`` spans,
-request lifecycle stamps double as ``request.*`` instant events (uid →
-TTFT/TPOT derivable from the trace alone, value-identical to ``stats()``),
+``reserve`` / ``cow`` / ``prefill`` / ``decode`` / ``complete`` spans
+(``prefill`` and ``decode`` each end in an ``engine.readback`` span, the
+wait for the logits on the host), request lifecycle stamps double as
+``request.*`` instant events (uid → queue wait and TTFT/TPOT derivable
+from the trace alone, value-identical to ``stats()``),
 and resident weight/cache bytes are gauged per step from the same registry
 accounting the dry-run twins predict.  ``ServeEngine(..., trace=True)``
 retains it all in a ring (:meth:`ServeEngine.timeline`); with no sink
@@ -169,8 +171,10 @@ class Request:
     ``state`` walks ``QUEUED → PREFILLING → DECODING → DONE``; ``cancel()``
     moves any non-terminal state to ``CANCELLED`` and the engine frees the
     slot at its next step.  ``on_token(req, tok)`` streams every emitted
-    token; ``arrival``/``first_token``/``finished`` are three-clock
-    :class:`~repro.serve.scheduler.Stamp` records (TTFT/TPOT inputs).
+    token; ``arrival``/``scheduled``/``first_token``/``finished`` are
+    three-clock :class:`~repro.serve.scheduler.Stamp` records (queue wait
+    and TTFT/TPOT inputs); ``scheduled`` is when it left the queue holding
+    a slot.
     """
 
     uid: Optional[int] = None
@@ -187,6 +191,7 @@ class Request:
     prefilled: int = 0
     on_token: Optional[Callable[["Request", int], None]] = None
     arrival: Optional[Stamp] = None
+    scheduled: Optional[Stamp] = None
     first_token: Optional[Stamp] = None
     finished: Optional[Stamp] = None
 
@@ -391,20 +396,33 @@ class ServeEngine:
     def _stamp(self) -> Stamp:
         return Stamp(self._clock(), self.step_index, self.work)
 
-    def _note_lifecycle(self, name: str, req: Request, stamp: Stamp) -> None:
+    def _note_lifecycle(self, name: str, req: Request, stamp: Stamp,
+                        **extra) -> None:
         """Emit one request-lifecycle instant carrying the EXACT stamp the
         engine recorded — :func:`repro.obs.metrics.request_stats_from_events`
         rebuilds TTFT/TPOT from these, value-identical to the Stamp path."""
         obs.event(name, uid=req.uid, state=req.state, t=stamp.time,
                   step=stamp.step, work=stamp.work,
-                  prompt_len=req.prompt_len, new_tokens=len(req.out))
+                  prompt_len=req.prompt_len, new_tokens=len(req.out),
+                  **extra)
+
+    def _note_scheduled(self, req: Request) -> None:
+        """Stamp the moment ``req`` left the queue holding a slot.  The
+        event also carries ``wait_s``, the two Stamps' difference, so the
+        queue wait reads from this one event even where the arrival mark
+        is no longer retained."""
+        req.scheduled = self._stamp()
+        if obs.active():
+            self._note_lifecycle(
+                "request.scheduled", req, req.scheduled,
+                wait_s=req.scheduled.time - req.arrival.time)
 
     def timeline(self) -> list:
         """All obs records retained by this engine's ring sink (requires
         ``trace=True`` at construction): span/point records in emission
         order — feed to :func:`repro.obs.export.chrome_trace`,
         :func:`repro.obs.metrics.summarize_spans` or
-        :func:`repro.obs.metrics.dispatch_table`."""
+        :func:`repro.obs.metrics.traced_kernels`."""
         if self._ring is None:
             raise RuntimeError(
                 "timeline() requires ServeEngine(..., trace=True)")
@@ -669,7 +687,8 @@ class ServeEngine:
                     else full.at[:, slot_ids].set(rows)
                 ),
             )
-        last_logits = np.asarray(logits[:, -1])
+        with obs.span("engine.readback"):
+            last_logits = np.asarray(logits[:, -1])
         for i, (slot, req, n) in enumerate(assignments):
             self.active[slot] = req
             self.pos[slot] = n
@@ -713,7 +732,8 @@ class ServeEngine:
             self.params, jnp.asarray(toks), self.caches, jnp.asarray(pos)
         )
         self.work += toks.size
-        step_logits = np.asarray(logits[:, -1])
+        with obs.span("engine.readback"):
+            step_logits = np.asarray(logits[:, -1])
         for slot, n in chunks:
             req = self.active[slot]
             req.prefilled += n
@@ -753,6 +773,7 @@ class ServeEngine:
                 try:
                     if self._try_attach_prefix(slot, req):
                         attached += 1  # prefix mapped; chunks do the suffix
+                        self._note_scheduled(req)
                         continue
                     if self._paged:
                         # reserve physical pages up front; under pool
@@ -769,6 +790,7 @@ class ServeEngine:
                                     scheduler=self.scheduler.name)
                     starved = True
                     break
+                self._note_scheduled(req)
                 refills.append((slot, req, min(n, len(req.prompt))))
         if refills:
             with obs.span("engine.prefill", slots=len(refills),

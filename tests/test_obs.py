@@ -5,14 +5,21 @@ tests, the zero-overhead disabled fast path), the derived-metrics math
 (percentile vs the numpy reference), the Chrome-trace exporter + schema
 validator, and the load-bearing engine integration: a traced
 ``bsdp_fused × int4_bp_fused × prefix_cache`` serving run whose timeline
-must export valid Chrome JSON with the step-loop spans and kernel
-dispatch counters, whose event-derived TTFT/TPOT must equal the engine's
-Stamp-based stats value-for-value, and whose resident-byte gauges must be
-byte-exact against the dry-run analytic twins.
+must export valid Chrome JSON with the step-loop spans and the kernels'
+trace-time call-site counters, whose event-derived TTFT/TPOT must equal
+the engine's Stamp-based stats value-for-value, and whose resident-byte
+gauges must be byte-exact against the dry-run analytic twins.  Also: spans
+mirrored into ``jax.profiler`` annotations, compiles counted under the
+span that caused them, and the ``request.scheduled`` stamp on the attach
+and requeue paths.
 """
 
 import io
 
+# imported up front: the first registered sink binds ``jax.profiler`` and
+# the compile listeners, and a first import inside a timed property
+# example would overrun its deadline
+import jax  # noqa: F401
 import numpy as np
 import pytest
 
@@ -167,7 +174,7 @@ class TestCountersAndGauges:
     def test_counter_fires_at_trace_time_under_jit(self):
         """Counters inside jitted code count call sites per compiled
         program: three executions of one compilation = one increment (the
-        kernel-dispatch semantics documented in kernels/ops.py)."""
+        ``kernel.traced`` semantics documented in kernels/ops.py)."""
         import jax
         import jax.numpy as jnp
 
@@ -258,12 +265,12 @@ class TestPercentile:
 
     def test_dispatch_table_counts_records(self):
         recs = [
-            obs.PointRecord("counter", "kernel.dispatch", 0.0, t,
+            obs.PointRecord("counter", "kernel.traced", 0.0, t,
                             {"kernel": k})
             for t, k in [(1, "a"), (2, "a"), (1, "b"), (3, "a")]
         ]
-        recs.append(obs.PointRecord("gauge", "kernel.dispatch", 0.0, 9, {}))
-        table = obs.dispatch_table(recs)
+        recs.append(obs.PointRecord("gauge", "kernel.traced", 0.0, 9, {}))
+        table = obs.traced_kernels(recs)
         assert table == {(("kernel", "a"),): 3, (("kernel", "b"),): 1}
 
 
@@ -414,17 +421,17 @@ class TestEngineTracing:
 
     def test_chrome_export_acceptance(self, traced_run):
         """The timeline exports valid Chrome JSON carrying the step-loop
-        spans AND kernel dispatch counter tracks — the ISSUE's acceptance
-        criterion for the all-fused run."""
+        spans AND the trace-time kernel call-site counter tracks of the
+        all-fused run."""
         doc = obs.chrome_trace(traced_run["timeline"])
         stats = obs.validate_chrome(doc)
         assert {"engine.plan", "engine.prefill",
                 "engine.decode"} <= stats["span_names"]
-        assert any(n.startswith("kernel.dispatch")
+        assert any(n.startswith("kernel.traced")
                    for n in stats["counter_names"])
 
     def test_dispatch_counters_cover_fused_kernels(self, traced_run):
-        table = obs.dispatch_table(traced_run["timeline"])
+        table = obs.traced_kernels(traced_run["timeline"])
         kernels = {dict(key).get("kernel") for key in table}
         assert "gemm_fused" in kernels   # the BSDP FFN single-contraction
         assert "plane_attn" in kernels   # the fused decode-attention read
@@ -463,3 +470,248 @@ class TestEngineTracing:
         assert by_name["request.arrival"] == uids
         assert by_name["request.first_token"] == uids
         assert by_name["request.finished"] == uids
+
+    def test_readback_nests_in_prefill_and_decode(self, traced_run):
+        """Each ``engine.readback`` (the wait for the logits) lies inside an
+        ``engine.prefill`` or ``engine.decode`` span, one level deeper, and
+        both kinds of parent hold one."""
+        spans = [r for r in traced_run["timeline"]
+                 if isinstance(r, obs.SpanRecord)]
+        parents = [r for r in spans
+                   if r.name in ("engine.prefill", "engine.decode")]
+        seen = set()
+        readbacks = [r for r in spans if r.name == "engine.readback"]
+        assert readbacks
+        for rb in readbacks:
+            (parent,) = [p for p in parents if p.ts <= rb.ts
+                         and rb.ts + rb.dur <= p.ts + p.dur]
+            assert rb.depth == parent.depth + 1
+            seen.add(parent.name)
+        assert seen == {"engine.prefill", "engine.decode"}
+
+    def test_scheduled_once_per_request(self, traced_run):
+        """``request.scheduled`` fires once per uid, between its arrival
+        and its first token, and carries the queue wait."""
+        events = [r for r in traced_run["timeline"]
+                  if isinstance(r, obs.PointRecord) and r.kind == "event"]
+        sched = [e for e in events if e.name == "request.scheduled"]
+        assert sorted(e.labels["uid"] for e in sched) == [0, 1, 2, 3]
+        by = {(e.name, e.labels["uid"]): e.labels for e in events}
+        for e in sched:
+            uid, t = e.labels["uid"], e.labels["t"]
+            arrival = by[("request.arrival", uid)]["t"]
+            assert arrival <= t <= by[("request.first_token", uid)]["t"]
+            assert e.labels["wait_s"] == t - arrival
+
+
+# ---------------------------------------------------------------------------
+# Spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+class _FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``; logs its life."""
+
+    log: list = []
+
+    def __init__(self, *args, **kwargs):
+        self.args, self.kwargs = args, kwargs
+        _FakeAnnotation.log.append(("new", args, kwargs))
+
+    def __enter__(self):
+        _FakeAnnotation.log.append(("enter", self.args))
+        return self
+
+    def __exit__(self, *exc):
+        _FakeAnnotation.log.append(("exit", self.args))
+        return False
+
+
+class TestProfilerAnnotations:
+    @pytest.fixture
+    def fake(self, monkeypatch):
+        import jax
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", _FakeAnnotation)
+        _FakeAnnotation.log = []
+        return _FakeAnnotation.log
+
+    def test_active_span_enters_annotation_with_its_name(self, fake):
+        obs.register_sink(obs.RingSink())
+        with obs.span("engine.prefill", slots=2, tokens=17):
+            with obs.span("engine.readback"):
+                pass
+        assert fake == [
+            ("new", ("engine.prefill",), {}), ("enter", ("engine.prefill",)),
+            ("new", ("engine.readback",), {}),
+            ("enter", ("engine.readback",)), ("exit", ("engine.readback",)),
+            ("exit", ("engine.prefill",)),
+        ]
+
+    def test_no_sink_builds_no_annotation(self, fake):
+        assert not obs.active()
+        for i in range(10):
+            s = obs.span(f"s{i}", a=i)
+            assert s is obs.NULL_SPAN
+            with s:
+                pass
+        spy = obs.register_sink(_SpySink())
+        with obs.disabled():
+            with obs.span("off"):
+                pass
+        assert fake == [] and spy.calls == 0
+
+    def test_annotation_closes_when_the_body_raises(self, fake):
+        obs.register_sink(obs.RingSink())
+        with pytest.raises(KeyError):
+            with obs.span("engine.step"):
+                raise KeyError("x")
+        assert fake[-1] == ("exit", ("engine.step",))
+        assert obs.current_depth() == 0
+
+    def test_spans_share_the_host_line_of_a_profiler_trace(self, tmp_path):
+        """In a real profiler trace the spans are host events on the same
+        thread line as an annotation the caller opened around them."""
+        import jax
+        from jax.profiler import ProfileData
+
+        obs.register_sink(obs.RingSink())
+        jax.profiler.start_trace(str(tmp_path))
+        with jax.profiler.TraceAnnotation("bench.step"):
+            with obs.span("engine.step"):
+                with obs.span("engine.readback"):
+                    pass
+        jax.profiler.stop_trace()
+        (path,) = tmp_path.glob("**/*.xplane.pb")
+        lines = [{e.name for e in line.events}
+                 for plane in ProfileData.from_file(str(path)).planes
+                 for line in plane.lines]
+        assert any({"bench.step", "engine.step", "engine.readback"} <= names
+                   for names in lines)
+
+
+# ---------------------------------------------------------------------------
+# Compiles attributed to spans
+# ---------------------------------------------------------------------------
+
+
+class TestCompileCounters:
+    def test_jit_compile_counted_under_innermost_span(self):
+        import jax
+        import jax.numpy as jnp
+
+        x13, x14 = jnp.ones(13), jnp.ones(14)   # made before tracing
+        obs.register_sink(obs.NullSink())
+
+        @jax.jit
+        def f(x):
+            return x * 3 + 1
+
+        with obs.span("engine.step"):
+            with obs.span("engine.prefill"):
+                f(x13).block_until_ready()
+        assert obs.counter_value("jax.compile", span="engine.prefill") == 1
+        assert obs.counter_value("jax.compile_s", span="engine.prefill") > 0
+        assert obs.counter_value("jax.compile", span="engine.step") == 0
+        f(x13).block_until_ready()      # cached: no compile
+        f(x14).block_until_ready()      # outside any span
+        assert obs.counter_value("jax.compile", span="none") == 1
+        assert obs.counter_total("jax.compile") == 2
+
+    def test_no_compile_counted_while_disabled(self):
+        import jax
+        import jax.numpy as jnp
+
+        x = jnp.ones(11)
+        obs.register_sink(obs.NullSink())
+        with obs.disabled():
+            jax.jit(lambda x: x - 5)(x).block_until_ready()
+        assert obs.counter_total("jax.compile") == 0
+
+    def test_cache_hit_counted_under_innermost_span(self):
+        import jax
+
+        obs.register_sink(obs.NullSink())
+        with obs.span("engine.decode"):
+            jax.monitoring.record_event(trace_mod.CACHE_HIT_EVENT)
+        jax.monitoring.record_event("/jax/some/other_event")
+        assert obs.counter_value("jax.compile_cache_hit",
+                                 span="engine.decode") == 1
+        assert obs.counter_total("jax.compile_cache_hit") == 1
+
+    def test_stats_line_prints_compiles(self):
+        out = io.StringIO()
+        sink = obs.register_sink(obs.StatsLineSink(every=1, stream=out))
+        obs.counter("jax.compile", 3, span="engine.prefill")
+        obs.counter("jax.compile_s", 1.25, span="engine.prefill")
+        sink.on_span(obs.SpanRecord("engine.step", 0.0, 0.01, 0, {}))
+        assert "3 compiles (1.2s)" in out.getvalue()
+        assert "dispatches" not in out.getvalue()
+
+
+# ---------------------------------------------------------------------------
+# request.scheduled on the attach and requeue paths
+# ---------------------------------------------------------------------------
+
+
+def _serve_traced(cache_format, scheduler, prompts, *, slots, pool_pages):
+    import jax
+
+    from repro.configs import get_smoke_config
+    from repro.models import model as model_lib
+    from repro.serve import engine
+    from repro.sharding import partitioning as P
+
+    cfg = get_smoke_config("qwen3-1.7b").scaled(n_layers=2, vocab_size=64)
+    params = P.materialize(model_lib.specs(cfg, 1), jax.random.PRNGKey(0))
+    eng = engine.ServeEngine(params, cfg, slots=slots, max_len=32,
+                             cache_format=cache_format, scheduler=scheduler,
+                             page_pool_pages=pool_pages, trace=True)
+    reqs = [eng.submit(p, 3) for p in prompts]
+    eng.run()
+    return eng, reqs
+
+
+def _scheduled(timeline):
+    return [r for r in timeline if isinstance(r, obs.PointRecord)
+            and r.kind == "event" and r.name == "request.scheduled"]
+
+
+class TestScheduledEvent:
+    def test_attach_path_schedules_once(self):
+        """Requests that attach a cached prefix leave the queue holding a
+        slot through the attach, and are stamped there once."""
+        rng = np.random.default_rng(1)
+        prefix = rng.integers(0, 64, 24).astype(np.int32)
+        prompts = [np.concatenate([prefix, rng.integers(0, 64, 3)
+                                   .astype(np.int32)]) for _ in range(5)]
+        eng, reqs = _serve_traced("paged_int4_bp", "prefix_cache", prompts,
+                                  slots=2, pool_pages=8)
+        timeline = eng.timeline()
+        assert eng.stats().pages["prefix_hits"] >= 1
+        sched = _scheduled(timeline)
+        assert sorted(e.labels["uid"] for e in sched) == [r.uid for r in reqs]
+        # an attached request is stamped already prefilling
+        assert any(e.labels["state"] == "prefilling" for e in sched)
+        assert all(r.scheduled is not None for r in reqs)
+        assert obs.request_stats_from_events(timeline) == \
+            eng.stats().requests
+
+    def test_requeue_does_not_schedule(self):
+        """A request sent back to the queue because the page pool is full
+        is stamped only when it finally gets its slot."""
+        rng = np.random.default_rng(2)
+        prompts = [rng.integers(0, 64, 9).astype(np.int32) for _ in range(3)]
+        eng, reqs = _serve_traced("paged_bf16", "fcfs", prompts,
+                                  slots=2, pool_pages=6)
+        timeline = eng.timeline()
+        requeues = [r for r in timeline if isinstance(r, obs.PointRecord)
+                    and r.name == "sched.requeue"]
+        assert requeues
+        sched = _scheduled(timeline)
+        assert sorted(e.labels["uid"] for e in sched) == [r.uid for r in reqs]
+        late = [e for e in sched if e.ts > requeues[0].ts]
+        assert late and all(e.labels["wait_s"] > 0 for e in late)
+        assert all(r.state == "done" for r in reqs)
+        assert obs.request_stats_from_events(timeline) == \
+            eng.stats().requests
