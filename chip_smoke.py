@@ -146,15 +146,23 @@ def check_logits(tag, refs, gots, *, tolerance=True):
 
 
 def serve(params, cfg, traffic, *, mode="bf16", cache_format=None,
-          scheduler="fcfs", max_new=None, force=None):
+          scheduler="fcfs", max_new=None, force=None, roomy=False):
     """Convert, serve ``traffic`` through the engine, return ``(engine,
-    requests, first-token logits per request)``."""
+    requests, first-token logits per request)``.  ``roomy``: a page pool
+    that holds every request's ring at once, so no request waits for pages
+    and no prefix is evicted."""
+    from repro.core import kvcache
     from repro.serve import engine
 
     qparams = engine.convert_params(params, cfg, mode)
+    pool = None
+    if roomy:
+        fmt = kvcache.get_cache_format(cache_format)
+        pool = len(traffic.suffix_lens) * fmt.pages_per_slot(traffic.max_len)
     eng = engine.ServeEngine(
         qparams, cfg, slots=traffic.slots, max_len=traffic.max_len,
-        cache_format=cache_format, scheduler=scheduler, trace_logits=True)
+        cache_format=cache_format, scheduler=scheduler, trace_logits=True,
+        page_pool_pages=pool)
     first = {}
 
     def on_token(req, _tok):
@@ -201,11 +209,18 @@ def phase_b(params, cfg, traffic, ref_first):
     """Serve the paper's stack and its jnp twin.  Logits are held to the
     CPU tests' bound only up to CHECK_LAYERS deep; deeper they are printed
     (W4A4 rounding turns tiny differences into large ones at depth).  The
-    fused kernel is always held to its twin directly, on the served cache."""
+    fused kernel is always held to its twin directly, on the served cache.
+
+    Both run on roomy page pools: the fused format's pages hold 128
+    positions, its twin's 8, so a shared prefix that ends inside a page
+    saves the fused run fewer pages, and under a pool that fills the two
+    would wait and evict differently; with room, slots and the token
+    budget alone set the schedule the twin check needs."""
     checked = cfg.n_layers <= CHECK_LAYERS
     eng, reqs, first = serve(
         params, cfg, traffic, mode=PHASE_B_MODE,
-        cache_format="paged_int4_bp_fused", scheduler="prefix_cache")
+        cache_format="paged_int4_bp_fused", scheduler="prefix_cache",
+        roomy=True)
     check_complete("B", reqs, traffic.max_new)
     check_logits(f"B vs bf16, {cfg.n_layers} layers", ref_first, first,
                  tolerance=checked)
@@ -217,7 +232,7 @@ def phase_b(params, cfg, traffic, ref_first):
     twin, twin_reqs, _ = serve(
         params, cfg, traffic, mode=PHASE_B_MODE,
         cache_format="paged_int4_bp", scheduler="prefix_cache",
-        force=[np.asarray(r.out) for r in reqs])
+        force=[np.asarray(r.out) for r in reqs], roomy=True)
     check_complete("B twin", twin_reqs, traffic.max_new)
     kinds = [(k, s) for k, s, _ in eng.logit_trace]
     check(kinds == [(k, s) for k, s, _ in twin.logit_trace],
@@ -229,9 +244,33 @@ def phase_b(params, cfg, traffic, ref_first):
     return eng
 
 
+def twin_cache(cache):
+    """Re-lay one layer of a ``paged_int4_bp_fused`` cache (page pool in
+    the kernel's layout) into ``paged_int4_bp``'s: every slot's ring,
+    gathered in order through its block table, paged again behind an
+    identity table."""
+    import jax.numpy as jnp
+
+    from repro.core import kvcache
+
+    fused = kvcache.get_cache_format("paged_int4_bp_fused")
+    twin = kvcache.get_cache_format("paged_int4_bp")
+    out = {"pos_ids": cache["pos_ids"]}
+    for prefix in ("k", "v"):
+        ring = fused._gather(fused.channel(cache, prefix))  # [B, L, ...]
+        b, ln = ring["_scale"].shape[:2]
+        n = b * ln // twin.page_size
+        store = {sfx: a.reshape(n, twin.page_size, *a.shape[2:])
+                 for sfx, a in ring.items()}
+        store["_pages"] = jnp.arange(n, dtype=jnp.int32).reshape(b, -1)
+        out.update(twin.channel_entries(prefix, store))
+    return out
+
+
 def check_fused_attention_on_cache(eng, cfg):
-    """Read layer 0 of phase B's real page pool through the fused kernel
-    and through the twin's plane math; the two formats share the layout."""
+    """Read layer 0 of phase B's real page pool through the fused kernel,
+    in place, and through the twin's plane math on the same positions
+    re-laid into the twin's layout."""
     import jax
     import jax.numpy as jnp
 
@@ -245,11 +284,12 @@ def check_fused_attention_on_cache(eng, cfg):
         size=(eng.slots, 1, cfg.n_heads, cfg.d_head)).astype(np.float32))
     cur = jnp.asarray(np.maximum(eng.pos - 1, 0), jnp.int32)
     outs = {}
-    for name in ("paged_int4_bp_fused", "paged_int4_bp"):
+    for name, c in (("paged_int4_bp_fused", cache),
+                    ("paged_int4_bp", twin_cache(cache))):
         fmt = kvcache.get_cache_format(name)
         with jax.default_matmul_precision("highest"):
             outs[name] = np.asarray(attention._decode_attention(
-                q, cache, cur=cur, window=None, fmt=fmt))
+                q, c, cur=cur, window=None, fmt=fmt))
     err = np.abs(outs["paged_int4_bp_fused"] - outs["paged_int4_bp"]).max()
     check(np.allclose(outs["paged_int4_bp_fused"], outs["paged_int4_bp"],
                       rtol=ATTN_TOL, atol=ATTN_TOL),
@@ -276,8 +316,9 @@ def check_mosaic_in_decode(eng):
 
 
 #: (ring length, query tokens per slot) for the decode-attention checks:
-#: the served ring, then a 32768-slot ring for one decode token and for a
-#: 32-token prefill chunk (the longest ring the kernel takes at G = 64)
+#: the served ring, then a 32768-slot ring, each for one decode token and
+#: for a 32-token prefill chunk (the kernel streams 128-position blocks,
+#: so the ring's length no longer bounds its VMEM)
 ATTENTION_CASES = ((1024, 1), (1024, 32), (32768, 1), (32768, 32))
 
 
@@ -287,7 +328,7 @@ def phase_c(cfg, *, batches=(1, 8, 128), attention_cases=ATTENTION_CASES):
 
     from repro.core import bitplane
     from repro.core.quant import QuantTensor
-    from repro.kernels import ops, ref
+    from repro.kernels import ops, plane_attn, ref
 
     rng = np.random.default_rng(SEED + 2)
     widths = [(cfg.d_model, cfg.d_ff), (cfg.d_ff, cfg.d_model)]
@@ -318,29 +359,51 @@ def phase_c(cfg, *, batches=(1, 8, 128), attention_cases=ATTENTION_CASES):
                       f"C: bsdp {kernel} m{m} {k}x{n} not bit-exact")
             n_checked += 5
 
-    r, feat = 4 * cfg.n_kv_heads, cfg.d_head
+    slots, heads, feat = 4, cfg.n_kv_heads, cfg.d_head
     group = cfg.n_heads // cfg.n_kv_heads
     fw = -(-feat // bitplane.WORD)
     for ln, tokens in attention_cases:
         g = tokens * group
+        # a ring of 128-position pages behind a shuffled block table, or
+        # one page per slot where the ring is no multiple of 128
+        page = plane_attn.block_len(ln)
+        npp = ln // page
+        table = rng.permutation(slots * npp).reshape(slots, npp)
 
-        def planes():
-            return bitplane.encode(ints((r, ln, fw * bitplane.WORD), -8, 8))
+        def pool(ring):  # [slots, H, L, W] → [pages, H, W, page]
+            x = ring.reshape(slots, heads, npp, page, -1)
+            x = x.transpose(0, 2, 1, 4, 3).reshape(slots * npp, heads, -1,
+                                                   page)
+            return jnp.zeros_like(x).at[table.ravel()].set(x)
 
-        args = dict(
-            q=ints((r, g, fw * bitplane.WORD), -8, 8),
-            q_scale=jnp.asarray(rng.uniform(0.01, 0.2, (r, g)), jnp.float32),
-            k_planes=planes(),
-            k_scale=jnp.asarray(rng.uniform(0.01, 0.2, (r, ln)), jnp.float32),
-            v_planes=planes(),
-            v_scale=jnp.asarray(rng.uniform(0.1, 1, (r, ln)), jnp.float32),
-            bias=jnp.asarray(np.broadcast_to(np.where(
-                np.arange(ln) < ln - 7, 0.0, -1e30), (r, g, ln)), jnp.float32),
-        )
-        out = ops.plane_decode_attention(**args, sm_scale=feat ** -0.5,
-                                         feat=feat)
-        exp = ref.plane_decode_attention_ref(**args, sm_scale=feat ** -0.5,
-                                             feat=feat)
+        planes = [bitplane.encode(ints((slots, heads, ln, fw * bitplane.WORD),
+                                       -8, 8)) for _ in range(2)]
+        scales = [jnp.asarray(rng.uniform(lo, hi, (slots, heads, ln)),
+                              jnp.float32) for lo, hi in ((0.01, 0.2),
+                                                          (0.1, 1))]
+        # slot i's queries sit at the end of its first (i+1)/4 of the
+        # ring; the ring's last 7 positions are empty
+        pos_ids = np.where(np.arange(ln) < ln - 7, np.arange(ln), -1)
+        q_pos = np.repeat((np.arange(1, slots + 1) * ln // slots - 1)[:, None],
+                          g, axis=1)
+        common = dict(
+            q=ints((slots, heads, g, fw * bitplane.WORD), -8, 8),
+            q_scale=jnp.asarray(rng.uniform(0.01, 0.2, (slots, heads, g)),
+                                jnp.float32),
+            q_pos=jnp.asarray(q_pos, jnp.int32),
+            pos_ids=jnp.asarray(np.broadcast_to(pos_ids, (slots, ln)),
+                                jnp.int32))
+        out = ops.plane_decode_attention(
+            **common, k_words=pool(planes[0].reshape(slots, heads, ln, -1)),
+            k_scale=pool(scales[0])[:, :, 0],
+            v_words=pool(planes[1].reshape(slots, heads, ln, -1)),
+            v_scale=pool(scales[1])[:, :, 0],
+            table=jnp.asarray(table, jnp.int32),
+            sm_scale=feat ** -0.5, feat=feat)
+        exp = ref.plane_decode_attention_ref(
+            **common, k_planes=planes[0], k_scale=scales[0],
+            v_planes=planes[1], v_scale=scales[1], sm_scale=feat ** -0.5,
+            feat=feat)
         err = float(jnp.max(jnp.abs(out - exp)))
         check(np.allclose(out, exp, rtol=ATTN_TOL, atol=ATTN_TOL),
               f"C: plane attention L{ln} G{g} off by {err:.3g}")

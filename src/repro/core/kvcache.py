@@ -114,6 +114,12 @@ class CacheFormat:
         ring length are dropped (negative/padded positions)."""
         raise NotImplementedError
 
+    def fill(self, store: dict, x: jax.Array, b_idx: jax.Array,
+             slots: jax.Array) -> dict:
+        """:meth:`append` into a ``store`` fresh from :meth:`init`, which
+        holds nothing to keep (a prefill); a format may build it anew."""
+        return self.append(store, x, b_idx, slots)
+
     def qk(self, q: jax.Array, store: dict) -> jax.Array:
         """Scores: ``q [B, *lead, G, F] · store [B, L, *lead, F] →
         [B, *lead, G, L]`` float32, scales folded after the contraction."""
@@ -125,12 +131,15 @@ class CacheFormat:
         raise NotImplementedError
 
     def decode_attention(self, q: jax.Array, k_store: dict, v_store: dict,
-                         bias: jax.Array, *, sm_scale: float,
+                         pos_ids: jax.Array, q_pos: jax.Array, *,
+                         window: Optional[int], sm_scale: float,
                          feat: int) -> jax.Array:
         """Fused qk → masked softmax → av in one kernel (only when
         ``supports_fused_decode``): ``q [B, H, G, F]`` against both channel
-        stores under the additive ``bias [B, H, G, L]`` mask →
-        ``[B, H, G, feat]`` float32."""
+        stores → ``[B, H, G, feat]`` float32.  Query row ``g`` of slot
+        ``b`` sits at position ``q_pos [B, G]`` and sees the stored
+        positions ``pos_ids [B, L]`` in ``[0, q_pos]`` (within ``window``);
+        a row that sees none reads zeros."""
         raise NotImplementedError(
             f"cache format {self.name!r} has no fused decode path"
         )
@@ -146,6 +155,10 @@ class CacheFormat:
         raise NotImplementedError
 
     # -- derived (generic) ----------------------------------------------
+    def kv_heads(self, store: dict) -> int:
+        """The lead (kv-head) dim of a GQA channel store."""
+        return store[""].shape[2]
+
     def slot_capacity(self, cache_len: int) -> int:
         """Ring length actually allocated for a requested ``cache_len`` —
         identity for contiguous formats; paged formats round up to a whole
@@ -402,12 +415,18 @@ class BitPlaneCacheFormat(CacheFormat):
             "_scale": jnp.zeros((batch, cache_len, *lead), jnp.float32),
         }
 
-    def append(self, store, x, b_idx, slots):
+    @staticmethod
+    def encode(x):
+        """``x [..., F]`` → (``[..., 4, Fw]`` uint32 int4 planes, ``[...]``
+        per-vector scales)."""
         scale = _slot_scale(x, 7)
         q = jnp.clip(
             jnp.round(x.astype(jnp.float32) / scale[..., None]), -8, 7
         ).astype(jnp.int8)
-        planes = bitplane.encode(bitplane.pad_to_word(q))  # [..., 4, Fw]
+        return bitplane.encode(bitplane.pad_to_word(q)), scale
+
+    def append(self, store, x, b_idx, slots):
+        planes, scale = self.encode(x)
         return {
             "": store[""].at[b_idx, slots].set(planes, mode="drop"),
             "_scale": store["_scale"].at[b_idx, slots].set(scale, mode="drop"),
@@ -492,51 +511,57 @@ class BitPlaneCacheFormat(CacheFormat):
                 "_scale": tuple(lead_axes)}
 
 
+def plane_attention(q, k_words, k_scale, v_words, v_scale, pos_ids, q_pos,
+                    table, *, window, sm_scale, feat, interpret=None):
+    """Quantize ``q [B, H, G, F]`` to int4 per vector and run the fused
+    page-aware kernel (:func:`repro.kernels.ops.plane_decode_attention`)
+    over a ``[pages, H, 4·Fw, page]`` plane pool read through ``table``."""
+    from repro.kernels import ops
+
+    qq_scale = _slot_scale(q, 7)  # [B, H, G]
+    qq = jnp.clip(
+        jnp.round(q.astype(jnp.float32) / qq_scale[..., None]), -8, 7
+    ).astype(jnp.int8)
+    return ops.plane_decode_attention(
+        bitplane.pad_to_word(qq), qq_scale, q_pos, k_words, k_scale,
+        v_words, v_scale, pos_ids, table, sm_scale=sm_scale, feat=feat,
+        window=window, interpret=interpret,
+    )
+
+
 class FusedBitPlaneCacheFormat(BitPlaneCacheFormat):
     """``int4_bp`` storage + the fused Pallas decode-attention kernel.
 
     Identical resident layout, bytes, ``append`` and sharding axes to
     ``int4_bp`` (it IS a :class:`BitPlaneCacheFormat`); the difference is
     pure kernel policy: GQA decode routes the whole qk → masked softmax →
-    av read through ONE Pallas pass per (batch × kv-head) row
-    (:func:`repro.kernels.ops.plane_decode_attention`), reading the stored
-    planes directly — one integer qk contraction, one av contraction,
-    per-slot scales folded after the integer math.  The jnp plane math of
+    av read through ONE Pallas pass (:func:`plane_attention`), reading the
+    stored planes directly — one integer qk contraction, one av
+    contraction, per-position scales folded after the integer math.  The
+    kernel reads page pools; this ring is handed over as a pool of one
+    page per slot (slot-minor words, an identity table).  The paged twin
+    (:class:`repro.core.paging.PagedPlaneCacheFormat`) stores its pool in
+    the kernel's layout and needs no such relayout.  The jnp plane math of
     the parent class is the reference semantics this kernel reproduces
-    (within softmax rounding); MLA decode
-    keeps the parent's qk/av because its score mixes a float rope term
-    between the two.
+    (within softmax rounding); MLA decode keeps the parent's qk/av because
+    its score mixes a float rope term between the two.
     """
 
     name = "int4_bp_fused"
     supports_fused_decode = True
 
-    def decode_attention(self, q, k_store, v_store, bias, *, sm_scale, feat,
-                         interpret=None):
-        from repro.kernels import ops
+    def decode_attention(self, q, k_store, v_store, pos_ids, q_pos, *,
+                         window, sm_scale, feat, interpret=None):
+        b, ln, h = k_store["_scale"].shape
 
-        b, h, g, _ = q.shape
-        qq_scale = _slot_scale(q, 7)  # [B, H, G]
-        qq = jnp.clip(
-            jnp.round(q.astype(jnp.float32) / qq_scale[..., None]), -8, 7
-        ).astype(jnp.int8)
-        qq = bitplane.pad_to_word(qq)  # [B, H, G, Fw·32]
-        k_planes = _to_l_minor(k_store[""], 2)  # [B, H, L, 4, Fw]
-        k_scale = _to_l_minor(k_store["_scale"], 0)  # [B, H, L]
-        v_planes = _to_l_minor(v_store[""], 2)
-        v_scale = _to_l_minor(v_store["_scale"], 0)
-        l, fw = k_planes.shape[2], k_planes.shape[-1]
-        out = ops.plane_decode_attention(
-            qq.reshape(b * h, g, fw * bitplane.WORD),
-            qq_scale.reshape(b * h, g),
-            k_planes.reshape(b * h, l, 4, fw),
-            k_scale.reshape(b * h, l),
-            v_planes.reshape(b * h, l, 4, fw),
-            v_scale.reshape(b * h, l),
-            bias.reshape(b * h, g, l),
-            sm_scale=sm_scale, feat=feat, interpret=interpret,
-        )
-        return out.reshape(b, h, g, feat)
+        def pool(store):  # [B, L, H, 4, Fw] → [B, H, 4·Fw, L]
+            words = store[""].reshape(b, ln, h, -1).transpose(0, 2, 3, 1)
+            return words, store["_scale"].transpose(0, 2, 1)
+
+        table = jnp.arange(b, dtype=jnp.int32)[:, None]
+        return plane_attention(
+            q, *pool(k_store), *pool(v_store), pos_ids, q_pos, table,
+            window=window, sm_scale=sm_scale, feat=feat, interpret=interpret)
 
 
 #: the name ISSUE/ROADMAP use for the bit-plane cache format class

@@ -12,31 +12,35 @@ schedulers (:mod:`repro.serve.scheduler`).
 Three pieces:
 
 * :class:`PagedCacheFormat` — a registered :class:`~repro.core.kvcache.
-  CacheFormat` adapter that lifts ANY inner format (``bf16``, ``int8``,
-  bit-plane ``int4_bp``/``int4_bp_fused``) from ``[B, L, ...]`` ring slots
-  onto a page pool: payload/scale arrays become ``[num_pages, page_size,
-  ...]`` (per-page quantization scales come for free — the inner format's
-  per-slot scales ARE per-page rows now) plus a ``[B, pages_per_slot]``
-  int32 **block table** per channel.  ``append`` translates ring offsets to
-  ``(physical page, in-page offset)`` scatters through the table;
-  ``qk``/``av``/``decode_attention`` gather the table back to the
-  contiguous layout and delegate to the inner format — so scores are
-  **bit-exact** with the ring cache whenever page contents match, for all
-  three plane kernels and the fused Pallas decode read alike.
+  CacheFormat` adapter that lifts an inner format (``bf16``, ``int8``,
+  bit-plane ``int4_bp``) from ``[B, L, ...]`` ring slots onto a page pool:
+  payload/scale arrays become ``[num_pages, page_size, ...]`` (per-page
+  quantization scales come for free — the inner format's per-slot scales
+  ARE per-page rows now) plus a ``[B, pages_per_slot]`` int32 **block
+  table** per channel.  ``append`` translates ring offsets to ``(physical
+  page, in-page offset)`` scatters through the table; ``qk``/``av``/
+  ``decode_attention`` gather the table back to the contiguous layout and
+  delegate to the inner format — so scores are **bit-exact** with the ring
+  cache whenever page contents match.
+
+* :class:`PagedPlaneCacheFormat` — ``paged_int4_bp_fused``: the bit-plane
+  pool in the fused decode kernel's own lane-dense layout, 128-position
+  pages, read in place through the block table (no gather).
 
 * :class:`PagePool` — the host-side physical allocator: refcounts,
   LIFO free list, COW/eviction/prefix-hit telemetry.  Pure numpy; the
   device arrays never resize (JAX pools are preallocated), the pool decides
   which rows are live, shared, or free.
 
-* :class:`RadixPrefixIndex` — a radix tree over page-granular token chunks
-  mapping tokenized prompt prefixes to physical pages.  The serving engine
-  registers a request's full prompt pages after prefill and maps matching
-  leading block-table entries of later requests onto the same physical
-  pages (refcounted; copy-on-write on the first divergent append, which
-  under ring recycling means the wrap write into a shared page).  Eviction
-  is least-recently-matched leaf first, exposed as scheduler data through
-  the pool stats in :class:`~repro.serve.scheduler.EngineView`.
+* :class:`RadixPrefixIndex` — a radix tree over token chunks of the
+  format's ``prefix_unit`` mapping tokenized prompt prefixes to physical
+  pages.  The serving engine registers a request's whole prompt chunks
+  after prefill and maps matching leading block-table entries of later
+  requests onto the same physical pages (refcounted; copy-on-write on the
+  first divergent append: a suffix that starts inside a shared page, or
+  under ring recycling the wrap write into a shared page).  Eviction is least-recently-matched leaf first, exposed as
+  scheduler data through the pool stats in
+  :class:`~repro.serve.scheduler.EngineView`.
 
 Registered names are ``paged_<inner>`` (``paged_bf16``, ``paged_int8``,
 ``paged_int4_bp``, ``paged_int4_bp_fused``): ``ServeEngine(cache_format=
@@ -47,6 +51,7 @@ call-site edits.
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -99,6 +104,10 @@ class PagedCacheFormat(kvcache.CacheFormat):
 
     #: tokens per page (power of two keeps slot→page arithmetic shift/mask)
     page_size: int = 8
+    #: a shared prefix may end at any multiple of this many tokens (capped
+    #: at the page size); a suffix that then starts inside a shared page
+    #: copies it first (copy-on-write)
+    prefix_unit: int = 8
 
     def __init__(self, inner: kvcache.CacheFormat,
                  page_size: Optional[int] = None,
@@ -107,6 +116,7 @@ class PagedCacheFormat(kvcache.CacheFormat):
             self.page_size = int(page_size)
         if self.page_size < 1:
             raise ValueError("page_size must be >= 1")
+        self.prefix_unit = min(self.prefix_unit, self.page_size)
         self.inner = inner
         self.name = name or f"paged_{inner.name}"
         self.is_bitplane = inner.is_bitplane
@@ -169,11 +179,17 @@ class PagedCacheFormat(kvcache.CacheFormat):
     def av(self, w, store, feat):
         return self.inner.av(w, self._gather(store), feat)
 
-    def decode_attention(self, q, k_store, v_store, bias, *, sm_scale, feat):
+    def decode_attention(self, q, k_store, v_store, pos_ids, q_pos, *,
+                         window, sm_scale, feat):
         return self.inner.decode_attention(
-            q, self._gather(k_store), self._gather(v_store), bias,
-            sm_scale=sm_scale, feat=feat,
+            q, self._gather(k_store), self._gather(v_store), pos_ids, q_pos,
+            window=window, sm_scale=sm_scale, feat=feat,
         )
+
+    def pages_read(self, max_pos, pages_per_slot: int):
+        """Pages one decode read touches per slot whose latest query
+        position is ``max_pos``: the block-table gather reads them all."""
+        return np.full_like(max_pos, pages_per_slot)
 
     # -- dry-run twin ----------------------------------------------------
     def abstract_state(self, batch, cache_len, lead, feat,
@@ -217,6 +233,166 @@ class PagedCacheFormat(kvcache.CacheFormat):
     def channel_entries(self, prefix, store):
         keys = self._keys(prefix)
         return {keys[sfx]: arr for sfx, arr in store.items()}
+
+
+class PagedPlaneCacheFormat(PagedCacheFormat):
+    """``paged_int4_bp_fused``: a bit-plane page pool in the layout the
+    fused decode kernel reads in place.
+
+    Storage per channel, pages leading so that splices, COW copies and
+    prefix attaches stay row operations on axis 0:
+
+    ``""``        ``[num_pages, *lead, 4·Fw, page]`` uint32 — plane ``j``,
+                  word ``w`` in row ``j·Fw + w``, one position per lane;
+    ``"_scale"``  ``[num_pages, *lead, page]`` float32;
+    ``"_pages"``  the ``[B, pages_per_slot]`` block table.
+
+    A page holds 128 positions, one lane tile, so every pool leaf is
+    lane-dense and a page is one ``[4·Fw, 128]`` tile per kv head — the
+    kernel's own tile.  GQA decode reads the pool in place through the
+    block table (:func:`repro.core.kvcache.plane_attention`), only the
+    pages up to each slot's latest query position.  ``qk``/``av`` (MLA,
+    whose score mixes a float rope term) gather back to the inner ring
+    layout and run the jnp plane math.
+    """
+
+    page_size = 128
+
+    def _pool(self, pages, lead, feat):
+        fw = self.inner._words(feat)
+        return {"": ((pages, *lead, 4 * fw, self.page_size), jnp.uint32),
+                "_scale": ((pages, *lead, self.page_size), jnp.float32)}
+
+    def init(self, batch, cache_len, lead, feat, dtype=jnp.bfloat16):
+        npp = self.pages_per_slot(cache_len)
+        store = {sfx: jnp.zeros(shape, dt) for sfx, (shape, dt)
+                 in self._pool(batch * npp, lead, feat).items()}
+        store["_pages"] = jnp.arange(
+            batch * npp, dtype=jnp.int32).reshape(batch, npp)
+        return store
+
+    def abstract_state(self, batch, cache_len, lead, feat,
+                       dtype=jnp.bfloat16):
+        npp = self.pages_per_slot(cache_len)
+        ab = {sfx: jax.ShapeDtypeStruct(shape, dt) for sfx, (shape, dt)
+              in self._pool(batch * npp, lead, feat).items()}
+        ab["_pages"] = jax.ShapeDtypeStruct((batch, npp), jnp.int32)
+        return ab
+
+    def append(self, store, x, b_idx, slots):
+        """Write ``x [B, S, *lead, F]`` at ring ``slots [B, S]``, each row's
+        valid slots consecutive (pads and the ring wrap aside), as every
+        prefill and decode write is.
+
+        A scatter of single positions would index the lane axis, and XLA
+        then relays the whole pool out around it.  Instead each row's
+        pages that the write can touch — at most ``(S + page − 2) // page
+        + 1`` consecutive ones — are gathered whole, the new positions are
+        gathered into place from the row's consecutive tokens (laid out
+        position-major, then moved to the lane axis), and only pages that
+        took a write are scattered back: whole pages, indexed on the
+        leading axis."""
+        del b_idx  # the block table row IS the batch index
+        table = store["_pages"]
+        b, npp = table.shape
+        page, s = self.page_size, slots.shape[1]
+        ln = npp * page
+        n_touch = min(npp, (s + page - 2) // page + 1)
+        valid = slots < ln  # slot == ring length: a dropped pad position
+        # a row's valid writes: n consecutive tokens from index first_i, at
+        # ring slots from `first` on (wrapping); rows with none write nothing
+        first_i = jnp.argmax(valid, axis=1)
+        first = jnp.take_along_axis(slots, first_i[:, None], axis=1)[:, 0]
+        p0 = first // page % npp
+        touched = (p0[:, None] + jnp.arange(n_touch)) % npp  # [B, T]
+        # position w of the touched pages holds token first_i + off[b, w]
+        off = jnp.arange(n_touch * page) - (first - p0 * page)[:, None]
+        written = (off >= 0) & (off < valid.sum(axis=1)[:, None])
+        src = jnp.clip(first_i[:, None] + off, 0, s - 1)  # [B, T·page]
+        written = written.reshape(b, n_touch, page)
+        phys = jnp.take_along_axis(table, touched, axis=1)
+        hit = written.any(axis=-1)
+        planes, scale = self.inner.encode(x)  # [B, S, *lead, 4, Fw]
+        words = planes.reshape(*planes.shape[:-2], -1)  # [B, S, *lead, 4Fw]
+
+        def merge(pool, vals):  # vals [B, S, *rest] → pool [P, *rest, page]
+            rest = vals.shape[2:]
+            new = jnp.take_along_axis(
+                vals, src.reshape(b, -1, *(1,) * len(rest)), axis=1)
+            new = jnp.moveaxis(new.reshape(b, n_touch, page, *rest), 2, -1)
+            mask = written.reshape(b, n_touch, *(1,) * len(rest), page)
+            # pages that took no write are not written back (a stale table
+            # row may map a page another slot writes this very step)
+            dst = jnp.where(hit, phys, pool.shape[0])
+            return pool.at[dst].set(jnp.where(mask, new, pool[phys]),
+                                    mode="drop")
+
+        return {"": merge(store[""], words),
+                "_scale": merge(store["_scale"], scale),
+                "_pages": table}
+
+    def fill(self, store, x, b_idx, slots):
+        """A prefill's write into a pool fresh from :meth:`init`: the rows
+        are built in ring order, token-major (a scatter of whole rows),
+        and cut into pages behind the identity table — no page is read."""
+        b, npp = store["_pages"].shape
+        planes, scale = self.inner.encode(x)  # [B, S, *lead, 4, Fw]
+
+        def pages(vals):  # [B, S, *rest] → [B·npp, *rest, page]
+            rest = vals.shape[2:]
+            ring = jnp.zeros((b, npp * self.page_size, math.prod(rest)),
+                             vals.dtype)
+            ring = ring.at[b_idx, slots].set(
+                vals.reshape(b, vals.shape[1], -1), mode="drop")
+            return jnp.moveaxis(
+                ring.reshape(b * npp, self.page_size, *rest), 1, -1)
+
+        return {"": pages(planes.reshape(*planes.shape[:-2], -1)),
+                "_scale": pages(scale), "_pages": store["_pages"]}
+
+    def _gather(self, store) -> dict:
+        """Block-table gather back to the inner ring layout ``[B, L,
+        *lead, 4, Fw]`` / ``[B, L, *lead]`` (the jnp ``qk``/``av`` read)."""
+        table = store["_pages"]
+        b, npp = table.shape
+        words = store[""][table]  # [B, npp, *lead, 4Fw, page]
+        scale = store["_scale"][table]  # [B, npp, *lead, page]
+        lead = words.shape[2:-2]
+        words = jnp.moveaxis(words, -1, 2)  # [B, npp, page, *lead, 4Fw]
+        return {
+            "": words.reshape(b, npp * self.page_size, *lead, 4, -1),
+            "_scale": jnp.moveaxis(scale, -1, 2).reshape(
+                b, npp * self.page_size, *lead),
+        }
+
+    def decode_attention(self, q, k_store, v_store, pos_ids, q_pos, *,
+                         window, sm_scale, feat):
+        return kvcache.plane_attention(
+            q, k_store[""], k_store["_scale"], v_store[""], v_store["_scale"],
+            pos_ids, q_pos, k_store["_pages"], window=window,
+            sm_scale=sm_scale, feat=feat)
+
+    def pages_read(self, max_pos, pages_per_slot: int):
+        """Pages the kernel reads: those up to the latest query position."""
+        from repro.kernels import plane_attn
+
+        return plane_attn.live_blocks(max_pos, self.page_size, pages_per_slot)
+
+    def kv_heads(self, store):
+        return store[""].shape[1]
+
+    def data_axes(self, lead_axes):
+        return {"": tuple(lead_axes) + (None, None),
+                "_scale": tuple(lead_axes) + (None,), "_pages": ()}
+
+    def flat_cache_axes(self, prefix, lead_axes):
+        """Pool leaves: ``(pages → kv_seq, *lead, plane rows and in-page
+        offset unsharded)``; block tables follow the batch axis."""
+        keys = self._keys(prefix)
+        axes = self.data_axes(lead_axes)
+        return {keys[""]: ("kv_seq",) + axes[""],
+                keys["_scale"]: ("kv_seq",) + axes["_scale"],
+                keys["_pages"]: ("batch", None)}
 
 
 # ---------------------------------------------------------------------------
@@ -376,11 +552,13 @@ class _Node:
 
 
 class RadixPrefixIndex:
-    """Radix tree keyed by page-sized token chunks.
+    """Radix tree keyed by ``unit``-sized token chunks: a page, or a whole
+    fraction of one where the format's pages are longer than its prefix
+    unit (:attr:`PagedCacheFormat.prefix_unit`).
 
-    Each node pins ONE physical page (the pool row holding that chunk's
-    K/V across every layer — pool rows index all layer pools identically,
-    so one page id is a whole-model page bundle).  ``match`` walks the
+    Each node pins one reference to ONE physical page (the pool row
+    holding that chunk's K/V across every layer — pool rows index all layer
+    pools identically, so one page id is a whole-model page bundle).  ``match`` walks the
     longest registered prefix and LRU-touches it; ``insert`` registers a
     served prompt's pages, returning only the NEWLY referenced ones so the
     caller can bump exactly those refcounts; ``evict_lru`` removes the
@@ -388,20 +566,20 @@ class RadixPrefixIndex:
     reachable).
     """
 
-    def __init__(self, page_size: int):
-        self.page_size = int(page_size)
+    def __init__(self, unit: int):
+        self.unit = int(unit)
         self.root: dict = {}
         self.size = 0
         self._stamp = 0
 
     def _chunks(self, tokens) -> list[tuple]:
         toks = tuple(int(t) for t in np.asarray(tokens).ravel())
-        n = len(toks) // self.page_size
-        return [toks[i * self.page_size:(i + 1) * self.page_size]
+        n = len(toks) // self.unit
+        return [toks[i * self.unit:(i + 1) * self.unit]
                 for i in range(n)]
 
     def match(self, tokens) -> np.ndarray:
-        """Physical pages of the longest registered page-aligned prefix."""
+        """Physical page of each chunk of the longest registered prefix."""
         self._stamp += 1
         pages, level = [], self.root
         for chunk in self._chunks(tokens):
@@ -414,9 +592,9 @@ class RadixPrefixIndex:
         return np.asarray(pages, np.int64)
 
     def insert(self, tokens, page_ids) -> list[int]:
-        """Register ``tokens``' page-aligned prefix backed by ``page_ids``;
-        returns the page ids newly referenced (existing chain nodes keep
-        their original pages — first writer wins)."""
+        """Register ``tokens``' whole chunks, chunk ``i`` backed by page
+        ``page_ids[i]``; returns the page ids newly referenced (existing
+        chain nodes keep their original pages — first writer wins)."""
         self._stamp += 1
         new, level = [], self.root
         for chunk, page in zip(self._chunks(tokens), np.asarray(page_ids)):
@@ -435,9 +613,9 @@ class RadixPrefixIndex:
         """Drop the least-recently-matched leaf; returns its page id (the
         caller releases the index's reference), or None when no leaf
         qualifies.  ``evictable(page_id)`` filters candidates — the engine
-        passes ``refs == 1`` so eviction only ever touches pages whose sole
-        holder is the index (evicting a page a live slot still maps would
-        burn an index entry without freeing a single byte)."""
+        passes "no live block table maps it", so eviction only ever touches
+        pages the index alone holds (evicting a page a live slot still maps
+        would burn an index entry without freeing a single byte)."""
         best = None  # (stamp, parent level, key, node)
 
         def walk(level):
@@ -463,5 +641,9 @@ class RadixPrefixIndex:
 PAGED_BASES = ("bf16", "int8", "int4_bp", "int4_bp_fused")
 
 for _base in PAGED_BASES:
-    kvcache.register_cache_format(
-        PagedCacheFormat(kvcache.get_cache_format(_base)))
+    _inner = kvcache.get_cache_format(_base)
+    # the fused kernel reads its own pool layout; the others gather
+    _cls = (PagedPlaneCacheFormat
+            if isinstance(_inner, kvcache.FusedBitPlaneCacheFormat)
+            else PagedCacheFormat)
+    kvcache.register_cache_format(_cls(_inner))

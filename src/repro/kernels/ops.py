@@ -357,37 +357,41 @@ def bsdp_gemv(
 
 
 def plane_decode_attention(
-    q: jax.Array,          # [R, G, Fw·32] int8, int4-valued
-    q_scale: jax.Array,    # [R, G] f32
-    k_planes: jax.Array,   # [R, L, 4, Fw] uint32
-    k_scale: jax.Array,    # [R, L] f32
-    v_planes: jax.Array,   # [R, L, 4, Fw] uint32
-    v_scale: jax.Array,    # [R, L] f32
-    bias: jax.Array,       # [R, G, L] f32 additive mask
+    q: jax.Array,          # [B, H, G, Fw·32] int8, int4-valued
+    q_scale: jax.Array,    # [B, H, G] f32
+    q_pos: jax.Array,      # [B, G] int32 position of each query row
+    k_words: jax.Array,    # [P, H, 4·Fw, page] uint32 page pool
+    k_scale: jax.Array,    # [P, H, page] f32
+    v_words: jax.Array,    # [P, H, 4·Fw, page] uint32
+    v_scale: jax.Array,    # [P, H, page] f32
+    pos_ids: jax.Array,    # [B, pages_per_slot·page] int32
+    table: jax.Array,      # [B, pages_per_slot] int32 block table
     *,
     sm_scale: float,
     feat: int,
+    window: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """Fused bit-plane decode attention → ``[R, G, feat]`` f32.
+    """Page-aware fused bit-plane decode attention → ``[B, H, G, feat]`` f32.
 
-    Wraps :func:`repro.kernels.plane_attn.plane_decode_attention`: the qk
-    scores, masked softmax and av gather run in ONE Pallas pass per
-    (batch × kv-head) row, contracting against the stored planes decoded in
-    VMEM, with all scales folded after the integer contraction.  Here the
-    planes move to the kernel's slot-minor ``[R, 4·Fw, L]`` layout and the
-    word-padded feature axis is sliced back to ``feat``.
+    Wraps :func:`repro.kernels.plane_attn.plane_decode_attention`: qk
+    scores, the position mask, an online softmax across the slot's pages
+    and the av read run in ONE Pallas pass, reading each slot's K/V pages
+    from the pool through its block table.  Only the blocks up to the one
+    holding the row's latest query position are read
+    (:func:`plane_attn.live_blocks`); the word-padded feature axis is
+    sliced back to ``feat``.
     """
-    r, l, _, fw = k_planes.shape
-
-    def words(planes):
-        return planes.reshape(r, l, 4 * fw).transpose(0, 2, 1)
-
-    _note_traced("plane_attn", l, feat)
+    b = q.shape[0]
+    blk = plane_attn.block_len(k_words.shape[-1])
+    n_blocks = pos_ids.shape[1] // blk
+    live = plane_attn.live_blocks(jnp.max(q_pos, axis=1), blk, n_blocks)
+    _note_traced("plane_attn", n_blocks * blk, feat)
     out = plane_attn.plane_decode_attention(
-        q, q_scale[..., None], words(k_planes), k_scale[:, None, :],
-        words(v_planes), v_scale[:, None, :], bias,
-        sm_scale=sm_scale, interpret=_interpret(interpret),
+        q, q_scale[..., None], q_pos[..., None], k_words, k_scale, v_words,
+        v_scale, pos_ids.reshape(b, n_blocks, blk), table,
+        live.astype(jnp.int32), sm_scale=sm_scale, window=window,
+        interpret=_interpret(interpret),
     )
     return out[..., :feat]
 

@@ -107,32 +107,42 @@ def dequant_matmul_ref(
 def plane_decode_attention_ref(
     q: jax.Array,
     q_scale: jax.Array,
+    q_pos: jax.Array,
     k_planes: jax.Array,
     k_scale: jax.Array,
     v_planes: jax.Array,
     v_scale: jax.Array,
-    bias: jax.Array,
+    pos_ids: jax.Array,
     *,
     sm_scale: float,
     feat: int,
+    window=None,
     signed: bool = True,
 ) -> jax.Array:
-    """Fused bit-plane decode-attention oracle: decode, then attend.
+    """Fused bit-plane decode-attention oracle: decode, mask, attend.
 
-    ``q [R, G, F]`` int4 values, ``k/v_planes [R, L, 4, F/32]``, per-row
-    scales ``[R, G]`` / ``[R, L]``, additive ``bias [R, G, L]`` →
-    ``[R, G, feat]`` f32.  Scores are exact int32 dot products; the value
-    read runs at highest matmul precision.
+    ``q [B, H, G, F]`` int4 values at positions ``q_pos [B, G]``; each
+    slot's ring in logical order, ``k/v_planes [B, H, L, 4, F/32]`` with
+    scales ``[B, H, L]`` and stored positions ``pos_ids [B, L]``; per-row
+    query scales ``[B, H, G]`` → ``[B, H, G, feat]`` f32.  A query row sees
+    the positions in ``[0, q_pos]`` (and within ``window``); one that sees
+    none reads zeros.  Scores are exact int32 dot products; the value read
+    runs at highest matmul precision.
     """
-    k = bitplane.decode(k_planes, signed=signed)  # [R, L, F] int8
+    k = bitplane.decode(k_planes, signed=signed)  # [B, H, L, F] int8
     v = bitplane.decode(v_planes, signed=signed).astype(jnp.float32)
-    s_int = jnp.einsum("rgf,rlf->rgl", q.astype(jnp.int32),
+    s_int = jnp.einsum("bhgf,bhlf->bhgl", q.astype(jnp.int32),
                        k.astype(jnp.int32),
                        preferred_element_type=jnp.int32)
     scores = (s_int.astype(jnp.float32) * q_scale[..., None]
-              * k_scale[:, None, :] * sm_scale + bias)
-    w = jax.nn.softmax(scores, axis=-1) * v_scale[:, None, :]
-    out = jnp.einsum("rgl,rlf->rgf", w, v,
+              * k_scale[:, :, None, :] * sm_scale)
+    pos, cur = pos_ids[:, None, None, :], q_pos[:, None, :, None]
+    valid = (pos >= 0) & (pos <= cur)
+    if window is not None:
+        valid &= pos > cur - window
+    w = jax.nn.softmax(jnp.where(valid, scores, -jnp.inf), axis=-1)
+    w = jnp.where(valid, w, 0.0) * v_scale[:, :, None, :]
+    out = jnp.einsum("bhgl,bhlf->bhgf", w, v,
                      precision=jax.lax.Precision.HIGHEST)
     return out[..., :feat]
 

@@ -146,7 +146,8 @@ def gqa_prefill(params, x, cfg, *, tp, cache_len, positions=None, impl=None,
     )
     out = dense(params["wo"], out.reshape(b, s, -1), impl=impl)
     cache = init_kv_cache(cfg, b, cache_len, tp=tp, dtype=k.dtype)
-    cache = _ring_write(cache, k, v, positions, kvcache.format_for(cfg))
+    cache = _ring_write(cache, k, v, positions, kvcache.format_for(cfg),
+                        fresh=True)
     return out, cache
 
 
@@ -217,14 +218,16 @@ def _ring_slots(positions, ln):
     return jnp.where(positions >= 0, positions % ln, ln)
 
 
-def _ring_write(cache, k, v, positions, fmt):
-    """Scatter S new (k, v) at slots = position mod L through the format."""
+def _ring_write(cache, k, v, positions, fmt, fresh=False):
+    """Scatter S new (k, v) at slots = position mod L through the format
+    (``fresh``: into a cache just built by :func:`init_kv_cache`)."""
     ln = cache["pos_ids"].shape[1]
     slots = _ring_slots(positions, ln)  # [B, S]
     b_idx = jnp.arange(k.shape[0], dtype=jnp.int32)[:, None]
+    write = fmt.fill if fresh else fmt.append
     out = dict(cache)
     for prefix, x in (("k", k), ("v", v)):
-        store = fmt.append(fmt.channel(cache, prefix), x, b_idx, slots)
+        store = write(fmt.channel(cache, prefix), x, b_idx, slots)
         out.update(fmt.channel_entries(prefix, store))
     out["pos_ids"] = cache["pos_ids"].at[b_idx, slots].set(
         positions, mode="drop")
@@ -246,13 +249,14 @@ def _decode_attention(q, cache, *, cur, window, fmt):
     contraction (``scores = (q·k_int)·scale``, ``out = (w·v_scale)·v_int``)
     and the bit-plane format contracts directly on the stored planes — the
     f32 cache copy is never materialized.  A format declaring
-    ``supports_fused_decode`` (``int4_bp_fused``) instead takes the whole
-    qk → masked softmax → av read in one fused kernel call, with the
-    position mask handed over as an additive bias — same semantics, one
-    kernel instead of three XLA computations.
+    ``supports_fused_decode`` (``int4_bp_fused`` and its paged twin)
+    instead takes the whole qk → masked softmax → av read in one fused
+    kernel call, which builds the position mask itself from ``pos_ids``
+    and each query row's position — same semantics, one kernel instead of
+    three XLA computations.
     """
     b, s, hq, dh = q.shape
-    hkv = cache["k"].shape[2]
+    hkv = fmt.kv_heads(fmt.channel(cache, "k"))
     g = hq // hkv
     ln = cache["pos_ids"].shape[1]
     qg = q.reshape(b, s, hkv, g, dh).transpose(0, 2, 1, 3, 4)
@@ -260,19 +264,17 @@ def _decode_attention(q, cache, *, cur, window, fmt):
     cur = jnp.asarray(cur, jnp.int32)
     cur = jnp.broadcast_to(cur[:, None] if cur.ndim == 1 else cur, (b, s))
     pos_ids = cache["pos_ids"]
-    valid = (pos_ids[:, None, :] >= 0) & (pos_ids[:, None, :] <= cur[..., None])
-    if window is not None:
-        valid &= pos_ids[:, None, :] > (cur[..., None] - window)
     if fmt.supports_fused_decode:
-        bias = jnp.where(valid, 0.0, NEG_INF).astype(jnp.float32)  # [B,S,L]
-        bias = jnp.broadcast_to(
-            bias[:, None, :, None, :], (b, hkv, s, g, ln)
-        ).reshape(b, hkv, s * g, ln)
         out = fmt.decode_attention(
-            qg, fmt.channel(cache, "k"), fmt.channel(cache, "v"), bias,
-            sm_scale=1.0 / math.sqrt(dh), feat=dh,
+            qg, fmt.channel(cache, "k"), fmt.channel(cache, "v"), pos_ids,
+            jnp.repeat(cur, g, axis=1),  # position of folded row s·G + g
+            window=window, sm_scale=1.0 / math.sqrt(dh), feat=dh,
         )  # [B, Hkv, S·G, D]
     else:
+        valid = ((pos_ids[:, None, :] >= 0)
+                 & (pos_ids[:, None, :] <= cur[..., None]))
+        if window is not None:
+            valid &= pos_ids[:, None, :] > (cur[..., None] - window)
         scores = fmt.qk(qg, fmt.channel(cache, "k"))  # [B, Hkv, S·G, L]
         scores = scores / math.sqrt(dh)
         scores = scores.reshape(b, hkv, s, g, ln)

@@ -310,7 +310,8 @@ class ServeEngine:
             pool_pages = (slots * self._npp if page_pool_pages is None
                           else int(page_pool_pages))
             self.page_pool = paging.PagePool(pool_pages, self._page)
-            self.prefix_index = paging.RadixPrefixIndex(self._page)
+            self.prefix_index = paging.RadixPrefixIndex(
+                self._fmt.prefix_unit)
             # host mirrors of the device block tables, one row per slot
             self._tables = np.zeros((slots, self._npp), np.int64)
             self._table_valid = np.zeros(slots, bool)
@@ -488,15 +489,18 @@ class ServeEngine:
                 self._finish(req, slot, req.state)
 
     # -- paged residency ------------------------------------------------
-    def _alloc_pages(self, n: int) -> np.ndarray:
+    def _alloc_pages(self, n: int, keep=()) -> np.ndarray:
         """Allocate ``n`` physical pages, evicting least-recently-matched
-        prefix-index leaves until the pool can satisfy the request."""
+        prefix-index leaves until the pool can satisfy the request; pages
+        a live block table maps, and ``keep``, are never evicted."""
+        mapped = set(self._tables[self._table_valid].ravel().tolist())
+        mapped.update(int(p) for p in keep)
         while True:
             try:
                 return self.page_pool.alloc(n)
             except paging.PoolExhausted:
                 page = self.prefix_index.evict_lru(
-                    lambda p: self.page_pool.refs[p] == 1)
+                    lambda p: p not in mapped)
                 if page is None:
                     raise
                 self.page_pool.release([page])
@@ -508,21 +512,39 @@ class ServeEngine:
         request enters PREFILLING with ``prefilled = matched_tokens`` so a
         chunk-planning scheduler advances only the unshared suffix; at
         least one suffix token is always left so the chunk path produces
-        the first-token logits."""
+        the first-token logits.  Where the format's prefix unit is below
+        its page, the prefix may end inside a page: the suffix writes into
+        that page at once, so it is copied into the slot's first private
+        page here instead of being mapped shared."""
         if not self._prefix_sharing or self.caches is None:
             return False
-        matched = self.prefix_index.match(req.prompt)
-        k = min(len(matched), (req.prompt_len - 1) // self._page,
-                self._npp - 1)
-        if k <= 0:
+        matched = self.prefix_index.match(req.prompt)  # a page per unit
+        unit = self.prefix_index.unit
+        per_page = self._page // unit
+        # the units of one page may name different pages (an attach that
+        # copied the page it ended inside registers the copy for the units
+        # after): the prefix stops at the first unit off its page's first
+        first = matched[np.arange(len(matched)) // per_page * per_page]
+        off = np.flatnonzero(matched != first)
+        n = min(off[0] if off.size else len(matched),
+                (req.prompt_len - 1) // unit, self._ring_len // unit - 1)
+        if n <= 0:
             return False
-        shared = matched[:k]
-        self.page_pool.retain(shared)
+        n_tok = n * unit
+        k = n_tok // self._page  # whole pages mapped shared
+        held = matched[:n:per_page]  # and the page the prefix ends inside
+        self.page_pool.retain(held)
         try:
-            private = self._alloc_pages(self._npp - k)
+            private = self._alloc_pages(self._npp - k, keep=held)
         except paging.PoolExhausted:
-            self.page_pool.release(shared)
+            self.page_pool.release(held)
             raise
+        shared = held[:k]
+        if len(held) > k:
+            self.caches = _tree_batched_named(
+                self.caches, _pool_copy(held[k:], private[:1]))
+            self.page_pool.release(held[k:])
+            self.page_pool.note_cow()
         self._tables[slot, :k] = shared
         self._tables[slot, k:] = private
         self._table_valid[slot] = True
@@ -530,7 +552,7 @@ class ServeEngine:
         self._shared_mask[slot, :k] = True
         table_row = jnp.asarray(self._tables[slot], jnp.int32)
         pos_row = np.full(self._ring_len, -1, np.int32)
-        pos_row[: k * self._page] = np.arange(k * self._page, dtype=np.int32)
+        pos_row[:n_tok] = np.arange(n_tok, dtype=np.int32)
         pos_row = jnp.asarray(pos_row)
 
         def attach(name, leaf, axis):
@@ -543,7 +565,6 @@ class ServeEngine:
             return leaf
 
         self.caches = _tree_batched_named(self.caches, attach)
-        n_tok = k * self._page
         self.active[slot] = req
         self.pos[slot] = n_tok
         req.prefilled = n_tok
@@ -552,37 +573,45 @@ class ServeEngine:
         return True
 
     def _register_prefix(self, slot: int, req: Request) -> None:
-        """Register a fully-prefilled prompt's page-aligned prefix in the
+        """Register a fully-prefilled prompt's whole prefix units in the
         radix index (called at the PREFILLING → DECODING transition)."""
         if not self._prefix_sharing:
             return
-        k = min(req.prompt_len // self._page, self._npp)
-        if k <= 0:
+        unit = self.prefix_index.unit
+        n = min(req.prompt_len, self._ring_len) // unit
+        if n <= 0:
             return
-        pages = self._tables[slot, :k]
-        new = self.prefix_index.insert(req.prompt[: k * self._page], pages)
+        pages = np.repeat(self._tables[slot], self._page // unit)[:n]
+        new = self.prefix_index.insert(req.prompt[: n * unit], pages)
         if new:
             self.page_pool.retain(new)
-        # any of this slot's prefix pages now multiply held (by the index
-        # or an attach donor) must COW before the ring wraps into them
-        for j in range(k):
+        # any of this slot's whole prompt pages now multiply held (by the
+        # index or an attach donor) must COW before the ring wraps into
+        # them.  A part-filled one it goes on writing past the prompt,
+        # where no other holder reads (an attach copies it): only the wrap
+        # copies it here
+        for j in range(min(req.prompt_len, self._ring_len) // self._page):
             if self.page_pool.refs[self._tables[slot, j]] > 1:
                 self._shared_mask[slot, j] = True
 
     def _cow_writes(self, writes) -> None:
         """Copy-on-write: before this step's appends, give every shared
         page about to be written a private copy.  ``writes`` rows are
-        ``(slot, positions)``; under ring recycling the first divergent
-        append IS the wrap write into a shared page."""
-        if not self._paged or not self._shared_mask.any():
+        ``(slot, positions)``.  A page is shared where the slot mapped it
+        so (``_shared_mask``), or where a write wraps the ring into a page
+        another holder maps; under ring recycling the first divergent
+        append IS that wrap write."""
+        if not self._paged:
             return
         ops = []
         for slot, positions in writes:
             for p in positions:
                 j = (int(p) % self._ring_len) // self._page
-                if not self._shared_mask[slot, j]:
-                    continue
                 old = int(self._tables[slot, j])
+                if not (self._shared_mask[slot, j]
+                        or (p >= self._ring_len
+                            and self.page_pool.refs[old] > 1)):
+                    continue
                 new = int(self._alloc_pages(1)[0])
                 ops.append((slot, j, old, new))
                 self._tables[slot, j] = new
@@ -593,19 +622,17 @@ class ServeEngine:
             return
         slots_a = jnp.asarray([o[0] for o in ops], jnp.int32)
         js_a = jnp.asarray([o[1] for o in ops], jnp.int32)
-        old_a = jnp.asarray([o[2] for o in ops], jnp.int32)
         new_a = jnp.asarray([o[3] for o in ops], jnp.int32)
 
-        def cow(name, leaf, axis):
-            if name in paging.POOL_KEYS:
-                return (leaf.at[new_a].set(leaf[old_a]) if axis == 0
-                        else leaf.at[:, new_a].set(leaf[:, old_a]))
+        def retable(name, leaf, axis):
             if name in paging.TABLE_KEYS:
                 return (leaf.at[slots_a, js_a].set(new_a) if axis == 0
                         else leaf.at[:, slots_a, js_a].set(new_a))
             return leaf
 
-        self.caches = _tree_batched_named(self.caches, cow)
+        self.caches = _tree_batched_named(self.caches, _pool_copy(
+            [o[2] for o in ops], [o[3] for o in ops]))
+        self.caches = _tree_batched_named(self.caches, retable)
 
     # -- execution ------------------------------------------------------
     def _prefill_slots(self, assignments: list[tuple[int, Request, int]]):
@@ -728,6 +755,8 @@ class ServeEngine:
         for slot in decode_slots:
             toks[slot, -1] = self.active[slot].out[-1]
             pos[slot, -1] = self.pos[slot]
+        if self._paged and obs.active():
+            self._note_pages_read(pos)
         logits, self.caches = self._decode(
             self.params, jnp.asarray(toks), self.caches, jnp.asarray(pos)
         )
@@ -756,6 +785,16 @@ class ServeEngine:
             if len(req.out) >= req.max_new:
                 finished.append((req, slot))
         return finished
+
+    def _note_pages_read(self, pos: np.ndarray) -> None:
+        """Count what one attention layer of this decode call reads: the
+        pages the cache format touches for rows at positions ``pos``
+        (``attn.pages_read``) and the pages the live block tables map
+        (``attn.pages_mapped``) — from host values, no device readback."""
+        read = self._fmt.pages_read(pos.max(axis=1), self._npp)
+        obs.counter("attn.pages_read", int(read.sum()))
+        obs.counter("attn.pages_mapped",
+                    int(self._table_valid.sum()) * self._npp)
 
     def _execute(self, plan: StepPlan) -> bool:
         """Run one validated :class:`StepPlan`; returns progress."""
@@ -898,6 +937,19 @@ class ServeEngine:
             self.caches)
         return {"weights": weights, "cache": cache,
                 "total": weights + cache}
+
+
+def _pool_copy(old, new):
+    """:func:`_tree_batched_named` function copying page-pool rows ``old``
+    to rows ``new`` in every layer."""
+    old, new = (jnp.asarray(np.asarray(a), jnp.int32) for a in (old, new))
+
+    def fn(name, leaf, axis):
+        if name not in paging.POOL_KEYS:
+            return leaf
+        return (leaf.at[new].set(leaf[old]) if axis == 0
+                else leaf.at[:, new].set(leaf[:, old]))
+    return fn
 
 
 def _tree_batched(caches, fn):

@@ -120,42 +120,62 @@ class TestBsdpKernel:
             assert bool(jnp.all(out == base)), (bm, bn, bkw)
 
 
-def plane_attention_inputs(rng, r, g, l, feat):
-    """Random operands of ``ops.plane_decode_attention``: int4 queries,
-    encoded K/V planes, positive scales and a mask with dead slots."""
+def plane_attention_inputs(rng, b, h, g, l, feat, page):
+    """Random operands of ``ops.plane_decode_attention`` over a shuffled
+    page pool, and the same operands in each slot's logical ring order
+    for ``ref.plane_decode_attention_ref``: int4 queries, encoded K/V
+    planes, positive scales, query positions and stored positions with
+    empty (-1) slots."""
     fw = -(-feat // 32)
-    q = rng.integers(-8, 8, size=(r, g, feat)).astype(np.int8)
-    q = np.pad(q, ((0, 0), (0, 0), (0, fw * 32 - feat)))
+    npp = l // page
+    q = rng.integers(-8, 8, size=(b, h, g, feat)).astype(np.int8)
+    q = np.pad(q, ((0, 0), (0, 0), (0, 0), (0, fw * 32 - feat)))
 
-    def planes():
-        vals = rng.integers(-8, 8, size=(r, l, feat)).astype(np.int8)
-        return bitplane.encode(bitplane.pad_to_word(jnp.asarray(vals)))
+    def planes():  # [B, H, L, 4, Fw]
+        vals = rng.integers(-8, 8, size=(b, h, l, feat)).astype(np.int8)
+        return np.asarray(bitplane.encode(bitplane.pad_to_word(
+            jnp.asarray(vals))))
 
-    bias = np.where(rng.random((r, g, l)) < 0.2, -1e30, 0.0)
-    bias[..., 0] = 0.0  # at least one live slot per query
-    return dict(
-        q=jnp.asarray(q),
-        q_scale=jnp.asarray(rng.uniform(0.1, 1.0, (r, g)), jnp.float32),
-        k_planes=planes(),
-        k_scale=jnp.asarray(rng.uniform(0.1, 1.0, (r, l)), jnp.float32),
-        v_planes=planes(),
-        v_scale=jnp.asarray(rng.uniform(0.1, 1.0, (r, l)), jnp.float32),
-        bias=jnp.asarray(bias, jnp.float32),
-    )
+    def pool(ring):  # [B, H, L, W] → pages [B·npp, H, W, page] via table
+        x = ring.reshape(b, h, npp, page, -1).transpose(0, 2, 1, 4, 3)
+        out = np.empty_like(x.reshape(b * npp, h, -1, page))
+        out[table.ravel()] = x.reshape(b * npp, h, -1, page)
+        return jnp.asarray(out)
+
+    table = rng.permutation(b * npp).reshape(b, npp).astype(np.int32)
+    pos_ids = np.where(rng.random((b, l)) < 0.2, -1, np.arange(l)[None])
+    q_pos = rng.integers(0, l, size=(b, g)).astype(np.int32)
+    q_pos[:, 0] = l - 1  # the last row sees the whole ring
+    k, v = planes(), planes()
+    ks, vs = (rng.uniform(0.1, 1.0, (b, h, l)).astype(np.float32)
+              for _ in range(2))
+    qs = rng.uniform(0.1, 1.0, (b, h, g)).astype(np.float32)
+    common = dict(q=jnp.asarray(q), q_scale=jnp.asarray(qs),
+                  q_pos=jnp.asarray(q_pos),
+                  pos_ids=jnp.asarray(pos_ids, jnp.int32))
+    kernel = dict(
+        common,
+        k_words=pool(k.reshape(b, h, l, 4 * fw)), k_scale=pool(ks)[:, :, 0],
+        v_words=pool(v.reshape(b, h, l, 4 * fw)), v_scale=pool(vs)[:, :, 0],
+        table=jnp.asarray(table))
+    oracle = dict(common, k_planes=jnp.asarray(k), k_scale=jnp.asarray(ks),
+                  v_planes=jnp.asarray(v), v_scale=jnp.asarray(vs))
+    return kernel, oracle
 
 
 class TestPlaneAttention:
-    @pytest.mark.parametrize("r,g,l,feat", [
-        (2, 2, 8, 16),       # one word, word-pad slack
-        (4, 6, 40, 128),     # full head dim, ragged ring
-        (3, 64, 16, 96),     # a 32-token chunk of group-2 queries
+    @pytest.mark.parametrize("b,h,g,l,feat,page", [
+        (2, 1, 2, 8, 16, 8),          # one word, word-pad slack, one page
+        (2, 2, 6, 384, 128, 128),     # full head dim, three pages a slot
+        (1, 3, 64, 256, 96, 256),     # a 32-token chunk, two blocks a page
     ])
-    def test_matches_oracle(self, r, g, l, feat):
-        rng = np.random.default_rng(r * 100 + g + l + feat)
-        args = plane_attention_inputs(rng, r, g, l, feat)
+    def test_matches_oracle(self, b, h, g, l, feat, page):
+        rng = np.random.default_rng(b * 100 + g + l + feat)
+        args, oracle = plane_attention_inputs(rng, b, h, g, l, feat, page)
         out = ops.plane_decode_attention(**args, sm_scale=0.125, feat=feat)
-        exp = ref.plane_decode_attention_ref(**args, sm_scale=0.125, feat=feat)
-        assert out.shape == (r, g, feat)
+        exp = ref.plane_decode_attention_ref(**oracle, sm_scale=0.125,
+                                             feat=feat)
+        assert out.shape == (b, h, g, feat)
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(exp), rtol=1e-4, atol=1e-4)
 

@@ -715,3 +715,53 @@ class TestScheduledEvent:
         assert all(r.state == "done" for r in reqs)
         assert obs.request_stats_from_events(timeline) == \
             eng.stats().requests
+
+
+# ---------------------------------------------------------------------------
+# attn.pages_read / attn.pages_mapped per decode call
+# ---------------------------------------------------------------------------
+
+
+class TestPageReadCounters:
+    @pytest.mark.parametrize("cache_format,read_share", [
+        ("paged_int4_bp_fused", 0.5),  # the live page of two
+        ("paged_int4_bp", 1.0),        # the gather reads every page
+    ])
+    def test_pages_read_and_mapped_per_decode(self, cache_format,
+                                              read_share):
+        """Each ``engine.decode`` counts the pages one attention layer
+        reads and the pages the live block tables map.  Prompts and
+        answers stay inside the first 128 positions of a 256-position
+        ring: the page-aware kernel reads that one page of a slot's two;
+        the block-table gather reads them all."""
+        import jax
+
+        from repro.configs import get_smoke_config
+        from repro.core import kvcache
+        from repro.models import model as model_lib
+        from repro.serve import engine
+        from repro.sharding import partitioning as P
+
+        cfg = get_smoke_config("qwen3-1.7b").scaled(n_layers=2,
+                                                    vocab_size=64)
+        params = P.materialize(model_lib.specs(cfg, 1),
+                               jax.random.PRNGKey(0))
+        eng = engine.ServeEngine(params, cfg, slots=2, max_len=256,
+                                 cache_format=cache_format, trace=True)
+        rng = np.random.default_rng(3)
+        for _ in range(2):
+            eng.submit(rng.integers(0, 64, 9).astype(np.int32), 4)
+        eng.run()
+        timeline = eng.timeline()
+        decodes = [r for r in timeline if isinstance(r, obs.SpanRecord)
+                   and r.name == "engine.decode"]
+        points = {name: [r for r in timeline
+                         if isinstance(r, obs.PointRecord)
+                         and r.name == name]
+                  for name in ("attn.pages_read", "attn.pages_mapped")}
+        assert len(decodes) == 3  # answers of 4: the first from prefill
+        assert all(len(p) == len(decodes) for p in points.values())
+        npp = kvcache.get_cache_format(cache_format).pages_per_slot(256)
+        mapped = points["attn.pages_mapped"][-1].value
+        assert mapped == len(decodes) * 2 * npp
+        assert points["attn.pages_read"][-1].value == read_share * mapped

@@ -21,6 +21,8 @@ Tentpole acceptance properties:
    contiguous) configs.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -29,6 +31,7 @@ import pytest
 from repro.configs import get_smoke_config
 from repro.core import kvcache, paging
 from repro.launch import dryrun
+from repro.models import attention
 from repro.models import model as model_lib
 from repro.serve.engine import ServeEngine
 from repro.sharding import partitioning as P
@@ -177,11 +180,14 @@ class TestPagedFormat:
         # contiguous formats keep the identity default
         assert kvcache.get_cache_format("bf16").slot_capacity(13) == 13
 
-    @pytest.mark.parametrize("base", ["bf16", "int8", "int4_bp"])
+    @pytest.mark.parametrize("base",
+                             ["bf16", "int8", "int4_bp", "int4_bp_fused"])
     def test_gather_is_bit_exact_vs_contiguous_ring(self, base):
         """Identity block tables + the same append stream ⇒ the paged
         gather and the contiguous ring hold identical bits, and qk/av
-        contract to identical results (wraparound overwrites included)."""
+        contract to identical results (wraparound overwrites included) —
+        also for the fused format's own ``[pages, *lead, 4·Fw, page]``
+        pool, whose gather relays it out to the ring layout."""
         inner = kvcache.get_cache_format(base)
         fmt = kvcache.get_cache_format(f"paged_{base}")
         B, L, lead, feat = 2, 2 * fmt.page_size, (2,), 32
@@ -189,11 +195,11 @@ class TestPagedFormat:
         sp = fmt.init(B, L, lead, feat)
         rng = np.random.default_rng(0)
         b_idx = jnp.arange(B, dtype=jnp.int32)[:, None]
-        for step, pos0 in enumerate((0, 6, 12, 20)):  # 20 wraps the ring
+        for step, pos0 in enumerate((0, 6, 12, 20)):  # 20·L/16 wraps
             S = 6
             x = jnp.asarray(
                 rng.normal(size=(B, S, *lead, feat)).astype(np.float32))
-            pos = pos0 + np.arange(S)
+            pos = pos0 * (L // 16) + np.arange(S)
             slots = np.broadcast_to(pos % L, (B, S)).copy()
             if step == 1:
                 slots[0, -1] = L  # a dropped (padded) position
@@ -213,6 +219,119 @@ class TestPagedFormat:
         np.testing.assert_array_equal(
             np.asarray(inner.av(w, si, feat)),
             np.asarray(fmt.av(w, sp, feat)))
+
+
+    def test_fused_fill_matches_append_into_a_fresh_pool(self):
+        """``paged_int4_bp_fused``'s prefill write (``fill``: rows built
+        token-major, then cut into pages) holds the bits ``append`` writes
+        into the same fresh pool — left pads and a part-filled page
+        included."""
+        fmt = kvcache.get_cache_format("paged_int4_bp_fused")
+        b, ln, s = 2, 2 * fmt.page_size, 150
+        rng = np.random.default_rng(0)
+        x = jnp.asarray(rng.normal(size=(b, s, 2, 32)), jnp.float32)
+        pos = np.arange(s) - np.array([[7], [0]])  # row 0: 7 pads
+        slots = jnp.asarray(np.where(pos >= 0, pos % ln, ln), jnp.int32)
+        b_idx = jnp.arange(b, dtype=jnp.int32)[:, None]
+        fresh = fmt.init(b, ln, (2,), 32)
+        want = fmt.append(fresh, x, b_idx, slots)
+        got = fmt.fill(fresh, x, b_idx, slots)
+        for key in want:
+            np.testing.assert_array_equal(np.asarray(got[key]),
+                                          np.asarray(want[key]))
+
+    def test_fused_append_in_a_pool_larger_than_its_tables(self):
+        """``paged_int4_bp_fused`` under a ``page_pool_pages`` above slots
+        × pages per slot: the pages a write touches but leaves unchanged
+        are written nowhere — not onto a real page past the tables that
+        another row writes in the same step."""
+        fmt = kvcache.get_cache_format("paged_int4_bp_fused")
+        b, ln, lead, feat = 2, 2 * fmt.page_size, (2,), 32
+        rng = np.random.default_rng(0)
+        x = jnp.asarray(rng.normal(size=(b, 32, *lead, feat)), jnp.float32)
+        slots = jnp.broadcast_to(jnp.arange(32, dtype=jnp.int32), (b, 32))
+        b_idx = jnp.arange(b, dtype=jnp.int32)[:, None]
+        ident = fmt.init(b, ln, lead, feat)  # pages 0-1 and 2-3
+        want = fmt.append(ident, x, b_idx, slots)
+        pool = {k: jnp.zeros((8, *a.shape[1:]), a.dtype)
+                for k, a in ident.items() if k != "_pages"}
+        # row 0 writes page 5, one past the tables' 4 entries
+        pool["_pages"] = jnp.asarray([[5, 6], [1, 2]], jnp.int32)
+        got = fmt.append(pool, x, b_idx, slots)
+        for key in ("", "_scale"):
+            g, w = np.asarray(got[key]), np.asarray(want[key])
+            np.testing.assert_array_equal(g[[5, 1]], w[[0, 2]])
+            assert not g[[0, 2, 3, 4, 6, 7]].any()
+
+
+#: (query tokens per slot, tokens written per slot, slot 1 shares slot 0's
+#: first 128 positions, slot 1's query rows are pads)
+PAGE_AWARE_CASES = {
+    "noncontiguous_part_filled_page": (1, (200, 77, 256), False, False),
+    "shared_prefix_pages": (1, (150, 140, 60), True, False),
+    "pad_positions": (4, (130, 20, 90), False, True),
+    "chunk_of_32": (32, (160, 32, 250), False, False),
+    "slot_with_no_live_page": (1, (100, 0, 129), False, False),
+}
+
+
+def _shuffle_pages(cache, rng, shared_pages):
+    """Move a paged cache's pages to random pool rows (tables follow);
+    slot 1's first ``shared_pages`` entries then map slot 0's pages."""
+    n = cache["k"].shape[0]
+    perm = rng.permutation(n)
+    out = dict(cache)
+    for name in paging.POOL_KEYS & set(cache):
+        out[name] = jnp.zeros_like(cache[name]).at[perm].set(cache[name])
+    for name in paging.TABLE_KEYS & set(cache):
+        table = perm[np.asarray(cache[name])]
+        table[1, :shared_pages] = table[0, :shared_pages]
+        out[name] = jnp.asarray(table, jnp.int32)
+    return out
+
+
+class TestPageAwareRead:
+    @pytest.mark.parametrize("case", sorted(PAGE_AWARE_CASES))
+    def test_page_aware_read_matches_gather_and_ring(self, case):
+        """The page-aware kernel (``paged_int4_bp_fused``, pool read in
+        place) equals the block-table gather + jnp plane math
+        (``paged_int4_bp``) and the unpaged fused kernel
+        (``int4_bp_fused``) on the same page contents; a query row that
+        sees no position reads zeros."""
+        s, lengths, shared, pad = PAGE_AWARE_CASES[case]
+        cfg, _ = _setup()
+        rng = np.random.default_rng(len(case))
+        b, ln = len(lengths), 256
+        kv = rng.normal(size=(2, b, ln, cfg.n_kv_heads, cfg.d_head))
+        if shared:
+            kv[:, 1, :128] = kv[:, 0, :128]
+        k, v = (jnp.asarray(a, jnp.float32) for a in kv)
+        written = np.where(np.arange(ln)[None] < np.array(lengths)[:, None],
+                           np.arange(ln)[None], -1).astype(np.int32)
+        cur = np.array([np.arange(n - s, n) for n in lengths], np.int32)
+        cur = np.where(cur >= 0, cur, -1)
+        if pad:
+            cur[1, : s // 2] = -1
+        q = jnp.asarray(rng.normal(
+            size=(b, s, cfg.n_heads, cfg.d_head)).astype(np.float32))
+        outs = {}
+        for name in ("int4_bp_fused", "paged_int4_bp", "paged_int4_bp_fused"):
+            fmt = kvcache.get_cache_format(name)
+            c = dataclasses.replace(cfg, cache_format=name)
+            cache = attention._ring_write(
+                attention.init_kv_cache(c, b, ln), k, v,
+                jnp.asarray(written), fmt)
+            if isinstance(fmt, paging.PagedCacheFormat):
+                cache = _shuffle_pages(cache, rng, 128 // fmt.page_size
+                                       if shared else 0)
+            outs[name] = np.asarray(attention._decode_attention(
+                q, cache, cur=jnp.asarray(cur), window=None, fmt=fmt))
+        sees = cur >= 0  # rows with at least position 0 to read
+        got = outs["paged_int4_bp_fused"]
+        for ref in ("paged_int4_bp", "int4_bp_fused"):
+            np.testing.assert_allclose(got[sees], outs[ref][sees],
+                                       rtol=1e-4, atol=1e-4)
+        assert not got[~sees].any()
 
 
 # ---------------------------------------------------------------------------
@@ -328,6 +447,53 @@ class TestPrefixSharing:
         st = eng.stats()
         assert st.pages["cow_copies"] >= 1
         assert st.pages["prefix_hits"] >= 1
+
+    def test_prefix_ending_inside_a_page_is_shared(self):
+        """Pages of 128 positions under ``paged_int4_bp_fused``: a request
+        registers its prompt when its prefill ends, part-filled page
+        included; requests arriving while it still decodes (into that
+        page) share its first 24 tokens, copy the page the prefix ends
+        inside at attach, and every token matches the unpaged engine."""
+        cfg, params = _setup()
+        prompts = self._shared_prompts(3, prefix_len=24, suffix_len=8)
+        _, ref = _serve(params, cfg, cache_format="int4_bp_fused",
+                        scheduler="prefix_cache", slots=3, max_len=64,
+                        prompts=prompts, max_news=(6, 3, 3))
+        eng = ServeEngine(params, cfg, slots=3, max_len=64,
+                          cache_format="paged_int4_bp_fused",
+                          scheduler="prefix_cache")
+        first = eng.submit(prompts[0], 6)
+        while first.state != "decoding":
+            eng.step()
+        eng.step()  # it writes past its prompt into the registered page
+        rest = [eng.submit(p, 3) for p in prompts[1:]]
+        eng.step()
+        assert first.state == "decoding"  # both attached while it runs
+        eng.run()
+        assert [r.out for r in [first, *rest]] == [r.out for r in ref]
+        st = eng.stats().pages
+        assert st["prefix_hits"] == 2 and st["prefix_tokens_saved"] == 48
+        assert st["cow_copies"] == 2  # each attach copies the shared page
+
+    def test_wrap_copies_a_registered_part_filled_page(self):
+        """A request goes on writing past its prompt into its registered
+        part-filled page (``paged_int4_bp_fused``, one 128-position page
+        per slot) until its ring wraps: the wrap write copies the page
+        first, so a later request sharing the prompt still reads it."""
+        cfg, params = _setup()
+        prompts = self._shared_prompts(2, prefix_len=24, suffix_len=8)
+        max_news = (110, 3)  # 32 + 110 > 128: the first request wraps
+        _, ref = _serve(params, cfg, cache_format="int4_bp_fused",
+                        scheduler="prefix_cache", slots=1, max_len=128,
+                        prompts=prompts, max_news=max_news)
+        eng, reqs = _serve(params, cfg, cache_format="paged_int4_bp_fused",
+                           scheduler="prefix_cache", slots=1, max_len=128,
+                           page_pool_pages=3, prompts=prompts,
+                           max_news=max_news)
+        assert [r.out for r in reqs] == [r.out for r in ref]
+        st = eng.stats().pages
+        assert st["prefix_hits"] == 1 and st["prefix_tokens_saved"] == 24
+        assert st["cow_copies"] == 2  # the wrap, then the attach
 
     def test_pool_too_small_for_one_request_raises(self):
         cfg, params = _setup()
