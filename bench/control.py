@@ -60,13 +60,13 @@ def main(argv=None) -> int:
                            "p90": float(np.percentile(g, 90)),
                            "p99": float(np.percentile(g, 99)),
                            "not_best": float((g > 0).mean())}
-        model = harness.model_dims(cell.config)
+        model = cell.model
         if model["kv_bits"] == 4:
             # the same served tokens against the reference with its query
             # rounded to int4 and not
             for q_bits in (4, 16):
                 gaps = harness.reference_gaps(
-                    cell.config, seed, out.sample,
+                    cell.family, cell.config, seed, out.sample,
                     {**model, "q_bits": q_bits})
                 row[f"max_logit_gap_q{q_bits}"] = (
                     float(gaps.max()) if len(gaps) else None)

@@ -1,10 +1,11 @@
 """Operations and bytes the work needs, computed from shapes alone.
 
-These are the numerators of ``mfu`` and of each kernel's roofline share.
-They count what the algorithm needs at the positions that hold a token, not
-what an implementation happens to touch: padding, empty ring slots, masks
-and logits nobody reads are left out, so a faster implementation can only
-raise a share towards 100%, never past it.
+These are the numerators of each kernel's roofline share, and with a
+family's ``rows_flops`` of ``mfu``.  They count what the algorithm needs at
+the positions that hold a token, not what an implementation happens to
+touch: padding, empty ring slots, masks and logits nobody reads are left
+out, so a faster implementation can only raise a share towards 100%, never
+past it.
 """
 
 from __future__ import annotations
@@ -22,29 +23,9 @@ class Rows(NamedTuple):
     kind: str  # "prefill" | "chunk" | "decode"
 
 
-def matmul_params(model: dict) -> int:
-    """Weights multiplied once per token by every layer's projections."""
-    d, h, kv, dh, ff = (model[k] for k in (
-        "d_model", "n_heads", "n_kv_heads", "d_head", "d_ff"))
-    attn = d * h * dh + 2 * d * kv * dh + h * dh * d
-    mlp = (3 if model["act"] == "silu" else 2) * d * ff
-    return model["n_layers"] * (attn + mlp)
-
-
-def _contexts(start: int, n: int) -> int:
+def contexts(start: int, n: int) -> int:
     """Sum of the context lengths of tokens at ``start … start+n-1``."""
     return n * start + n * (n + 1) // 2
-
-
-def rows_flops(model: dict, rows) -> int:
-    """Model FLOPs of the given work: per token, the projections and the
-    score and value contractions over its real context (``position + 1``
-    keys); the output head once for each row whose logits are read."""
-    per_ctx = 4 * model["n_layers"] * model["n_heads"] * model["d_head"]
-    head = 2 * model["d_model"] * model["vocab_size"]
-    dense = 2 * matmul_params(model)
-    return sum(r.n * dense + per_ctx * _contexts(r.start, r.n)
-               + (head if r.logits else 0) for r in rows)
 
 
 #: bytes one cached position holds per kv head in an int4 bit-plane cache:
@@ -71,7 +52,7 @@ def plane_attn_work(model: dict, rows) -> tuple[int, int]:
         if r.kind == "prefill":
             continue  # the first chunk runs the prefill path, not the kernel
         held = r.start + r.n
-        ops += kv * group * 4 * dh * _contexts(r.start, r.n)
+        ops += kv * group * 4 * dh * contexts(r.start, r.n)
         nbytes += kv * (held * plane_position_bytes(dh)
                         + r.n * group * dh * (1 + 4))
     return ops, nbytes

@@ -3,8 +3,10 @@
 A cell is an entry of ``BENCHMARK.json``'s ``workloads``: a configuration
 (``bench/configs/<config>.json``) under a traffic mix
 (``bench/traffic/<mix>.json``).  Everything is found by name, so a later
-change adds a configuration, a mix or a per-layer metric
-(``bench/metrics/<metric>.py``) by adding a file.
+change adds a configuration, a mix, a per-layer metric
+(``bench/metrics/<metric>.py``) or an architecture family
+(``bench/families/<family>.py``, named by the configuration's ``family``)
+by adding a file.
 
 The system under test is the repository's serving path: random weights from
 the seed → ``ServeEngine`` (which converts them to the configuration's
@@ -17,13 +19,16 @@ client side:
 * the window: ``seconds`` of the mix's traffic.  The loop submits every
   request whose due time has come, steps, and sleeps only while the engine
   is idle.  Requests are timed from when they were due, not when they were
-  sent;
+  sent; in a closed mix a request is due when its user sends it, and the
+  users carry on from warm-up into the window;
 * afterwards, ``correct``: with no new arrivals the engine runs on until
-  the requests finished since the window opened hold enough served tokens;
-  a sample of them, drawn from the seed with the longest among them, is run
-  through the plain float32 reference (:mod:`bench.reference`) and every
-  served token's gap below the reference's best logit is compared with the
-  configuration's limit.
+  the requests finished since the window opened hold enough served tokens
+  (and, where the mix names ``checked_requests``, that many requests);
+  a sample of them, drawn from the seed with the longest among them and,
+  up to ``checked_requests``, one from each slot the sample lacks first, is
+  run through the plain float32 reference (:mod:`bench.reference`, the
+  family's forward pass) and every served token's gap below the
+  reference's best logit is compared with the configuration's limit.
 """
 
 from __future__ import annotations
@@ -59,34 +64,29 @@ class Cell:
     mix: dict             # bench/traffic/<mix>.json
     end_to_end: list      # BENCHMARK.json metric entries reported here
     per_layer: list
+    family: object        # bench/families/<config["family"]>.py
 
     @property
     def model(self) -> dict:
-        return model_dims(self.config)
+        return self.family.dims(self.config)
 
 
-def model_dims(config: dict) -> dict:
-    """The sizes the weights, the reference and the counts read, from the
-    published ``config.json`` keys the configuration file carries."""
-    heads = config["num_attention_heads"]
-    return {
-        "n_layers": config["num_hidden_layers"],
-        "d_model": config["hidden_size"],
-        "n_heads": heads,
-        "n_kv_heads": config["num_key_value_heads"],
-        "d_head": config.get("head_dim", config["hidden_size"] // heads),
-        "d_ff": config["intermediate_size"],
-        "vocab_size": config["vocab_size"],
-        "norm": ("layernorm" if config.get("norm_type") == "layer_norm"
-                 else "rmsnorm"),
-        "norm_eps": config.get("rms_norm_eps", config.get("norm_epsilon")),
-        "act": "gelu" if config["hidden_act"].startswith("gelu") else "silu",
-        "qk_norm": config["model_type"] == "qwen3",
-        "tie_embeddings": bool(config.get("tie_word_embeddings", False)),
-        "rope_theta": float(config["rope_theta"]),
-        "kv_bits": config["kv_cache_bits"],
-        "q_bits": config.get("attention_query_bits", 16),
-    }
+def load_family(name: str, root: pathlib.Path = ROOT):
+    """The architecture family ``bench/families/<name>.py`` under ``root``:
+    ``dims``, ``shapes``, ``program_tree``, ``program_config``,
+    ``logits_rows`` and ``rows_flops`` (see ``bench/families/dense.py``)."""
+    path = root / "bench" / "families" / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"architecture family {name!r}: no file {path}")
+    return _load_module(path, f"bench_family_{name}")
+
+
+def _load_module(path: pathlib.Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def _applies(metric: dict, cell: str) -> bool:
@@ -108,6 +108,7 @@ def cell_from(spec: dict, name: str, root: pathlib.Path = ROOT) -> Cell:
         mix=traffic_lib.load_mix(w["traffic"]),
         end_to_end=[m for m in spec["end_to_end"] if _applies(m, name)],
         per_layer=[m for m in spec["per_layer"] if _applies(m, name)],
+        family=load_family(config["family"], root),
     )
 
 
@@ -157,33 +158,19 @@ def enable_compile_cache(path: pathlib.Path = CACHE_DIR) -> None:
 # The system under test
 # ---------------------------------------------------------------------------
 
-#: configuration-file model keys → the program's ModelConfig fields
-_MODEL_FIELDS = ("n_layers", "d_model", "n_heads", "n_kv_heads", "d_head",
-                 "d_ff", "vocab_size", "norm", "act", "qk_norm",
-                 "tie_embeddings", "rope_theta")
 
-
-def program_config(config: dict):
-    """The program's ModelConfig for the configuration file: the registered
-    architecture with the file's sizes (a test may shrink them)."""
-    from repro.configs import get_config
-
-    model = model_dims(config)
-    cfg = get_config(config["program_model"])
-    return dataclasses.replace(cfg, **{k: model[k] for k in _MODEL_FIELDS})
-
-
-def build_engine(config: dict, seed: int, *, mode: Optional[str] = None,
-                 trace_sink=None):
+def build_engine(family, config: dict, seed: int, *,
+                 mode: Optional[str] = None, trace_sink=None):
     """Weights from ``seed`` → ``ServeEngine`` with the configuration's
     stack (``mode`` replaces the weight residency, for the control)."""
     from bench import weights
     from repro.serve import engine
 
     stack = config["stack"]
-    params = weights.program_params(model_dims(config), seed)
+    model = family.dims(config)
+    params = weights.program_params(family, model, seed)
     eng = engine.ServeEngine(
-        params, program_config(config), slots=stack["slots"],
+        params, family.program_config(config, model), slots=stack["slots"],
         max_len=stack["max_len"], mode=mode or stack["mode"],
         cache_format=stack["cache_format"], scheduler=stack["scheduler"],
         trace=trace_sink if trace_sink is not None else False)
@@ -234,6 +221,7 @@ class Sent:
     sent: float = 0.0
     req: object = None
     times: list = dataclasses.field(default_factory=list)   # token stamps
+    slot: Optional[int] = None      # the engine slot of its first token
 
 
 class StepLog:
@@ -291,8 +279,11 @@ class Sender:
     def submit(self, spec, due: float) -> Sent:
         s = Sent(spec, due)
 
-        def on_token(_req, _tok, times=s.times, clock=self.clock):
-            times.append(clock())
+        def on_token(req, _tok, s=s, clock=self.clock, eng=self.eng):
+            if not s.times:
+                s.slot = next((i for i, a in enumerate(eng.active)
+                               if a is req), None)
+            s.times.append(clock())
 
         s.sent = self.clock()
         s.req = self.eng.submit(spec.prompt, spec.max_new, on_token=on_token)
@@ -327,11 +318,52 @@ class Sender:
                     time.sleep(max(0.0, wake - clock()))
         for j in range(i, len(specs)):   # due in the window, sent late
             window.append(self.submit(specs[j], due[j]))
-        deadline = clock() + drain_s
-        while (any(not s.times for s in window) and _busy(eng)
-               and clock() < deadline):
-            step()
+        self._drain(window, step, drain_s)
         return window, t0
+
+    def closed(self, users: traffic_lib.ClosedUsers, seconds: float,
+               step: Callable, drain_s: float = 60.0) -> tuple:
+        """Keep each of a closed mix's ``users`` with one request in flight
+        for ``seconds``: a user sends its first request at its start time
+        and each next one, due when it is sent, once the last has finished.
+        Requests in flight when the call starts run on.  Then drain as
+        :meth:`open` does.  Returns the requests sent in this call and its
+        start."""
+        clock, eng = self.clock, self.eng
+        t0 = clock()
+        end = t0 + seconds
+        if users.t_start is None:
+            users.t_start = t0
+        starts = [users.t_start + users.start_s(u) for u in range(users.count)]
+        window = []
+        while True:
+            now = clock()
+            if now >= end:
+                break
+            with _annotate("bench.generate"):
+                for u, s in enumerate(users.inflight):
+                    if s.req.done if s is not None else now >= starts[u]:
+                        users.inflight[u] = self.submit(
+                            users.next_request(u), now)
+                        window.append(users.inflight[u])
+            if _busy(eng):
+                with _annotate("bench.step"):
+                    step()
+            else:   # users not started yet
+                wake = min([starts[u] for u, s in enumerate(users.inflight)
+                            if s is None] + [end])
+                with _annotate("bench.idle"):
+                    time.sleep(max(0.0, wake - clock()))
+        self._drain(window, step, drain_s)
+        return window, t0
+
+    def _drain(self, window: list, step: Callable, drain_s: float) -> None:
+        """With no new arrivals, step until every request of ``window`` has
+        its first token (at most ``drain_s``)."""
+        deadline = self.clock() + drain_s
+        while (any(not s.times for s in window) and _busy(self.eng)
+               and self.clock() < deadline):
+            step()
 
 
 # ---------------------------------------------------------------------------
@@ -360,55 +392,67 @@ def end_to_end(sent: list, window: list, t0: float, seconds: float,
     return out
 
 
-def _finished_tokens(sent: list, t0: float) -> int:
-    return sum(len(s.req.out) for s in sent
-               if s.req.state == "done" and s.times and s.times[-1] >= t0)
+def _finished(sent: list, t0: float) -> list:
+    return [s for s in sent
+            if s.req.state == "done" and s.times and s.times[-1] >= t0]
 
 
 def finish_for_check(eng, sent: list, t0: float, min_tokens: int,
-                     limit_s: float = 60.0) -> None:
+                     min_requests: int = 1, limit_s: float = 60.0) -> None:
     """After the window, with no new arrivals: step until the requests
-    finished since the window opened hold ``min_tokens`` served tokens, the
-    engine is idle, or ``limit_s`` has passed."""
+    finished since the window opened hold ``min_tokens`` served tokens and
+    number ``min_requests``, the engine is idle, or ``limit_s`` has
+    passed."""
     deadline = time.perf_counter() + limit_s
-    while (_finished_tokens(sent, t0) < min_tokens and _busy(eng)
-           and time.perf_counter() < deadline):
+
+    def short():
+        done = _finished(sent, t0)
+        return (sum(len(s.req.out) for s in done) < min_tokens
+                or len(done) < min_requests)
+
+    while short() and _busy(eng) and time.perf_counter() < deadline:
         eng.step()
 
 
 def sample_finished(sent: list, t0: float, seed: int, min_tokens: int,
-                    max_requests: int) -> list:
+                    max_requests: int, min_requests: int = 1) -> list:
     """Of the requests finished after the window opened, the longest, then
-    others drawn from the seed, until ``min_tokens`` served tokens or
-    ``max_requests`` requests."""
-    done = [s for s in sent
-            if s.req.state == "done" and s.times and s.times[-1] >= t0]
+    others drawn from the seed, until ``min_tokens`` served tokens and
+    ``min_requests`` requests, or ``max_requests`` requests.  Up to
+    ``min_requests``, a request on a slot the sample lacks comes first."""
+    done = _finished(sent, t0)
     if not done:
         return []
     done.sort(key=lambda s: (-(s.req.prompt_len + len(s.req.out)), s.req.uid))
     pick = [done[0]]
-    rest = done[1:]
     order = np.random.default_rng(
-        np.random.SeedSequence([int(seed), 11])).permutation(len(rest))
-    for j in order:
-        if (sum(len(s.req.out) for s in pick) >= min_tokens
-                or len(pick) >= max_requests):
+        np.random.SeedSequence([int(seed), 11])).permutation(len(done) - 1)
+    rest = [done[1 + j] for j in order]
+    if min_requests > 1:
+        slots, first = {pick[0].slot}, []
+        for s in rest:
+            if len(first) < min_requests - 1 and s.slot not in slots:
+                slots.add(s.slot)
+                first.append(s)
+        rest = first + [s for s in rest if all(s is not f for f in first)]
+    for s in rest:
+        if ((sum(len(x.req.out) for x in pick) >= min_tokens
+             and len(pick) >= min_requests) or len(pick) >= max_requests):
             break
-        pick.append(rest[j])
-    return [(np.asarray(s.req.prompt, np.int32),
-             np.asarray(s.req.out, np.int32)) for s in pick]
+        pick.append(s)
+    return pick
 
 
-def reference_gaps(config: dict, seed: int, sample: list,
+def reference_gaps(family, config: dict, seed: int, sample: list,
                    model: Optional[dict] = None) -> np.ndarray:
     """Every served token's gap below the plain reference's best logit
     (``model`` replaces the configuration's sizes and precisions)."""
     from bench import reference, weights
 
-    model = model or model_dims(config)
-    w = weights.reference_weights(model, seed)
+    model = model or family.dims(config)
+    w = weights.reference_weights(family, model, seed)
     row_cap = config["stack"]["max_new_cap"]
-    gaps = [reference.served_gaps(model, w, p, o,
+    gaps = [reference.served_gaps(family, model, w, p, o,
                                   config["stack"]["max_len"], row_cap)[0]
             for p, o in sample]
     del w
@@ -425,11 +469,8 @@ def device_info(devices) -> dict:
 
 
 def load_reader(name: str):
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module(BENCH / "metrics" / f"{name}.py",
+                        f"bench_metric_{name}").read
 
 
 @dataclasses.dataclass
@@ -481,10 +522,17 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
     compiles = CompileClock()
     config, mix = cell.config, cell.mix
     ring = obs.RingSink(capacity=1 << 21) if trace else None
-    eng = build_engine(config, seed, mode=mode, trace_sink=ring)
+    eng = build_engine(cell.family, config, seed, mode=mode, trace_sink=ring)
     warm_shapes(eng, config, seed)
-    sched = traffic_lib.schedule(mix, seed, seconds, config["vocab_size"])
+    vocab = config["vocab_size"]
     drv = Sender(eng, clock)
+    if mix["kind"] == "closed":   # the same users from warm-up on
+        warm = measured = traffic_lib.ClosedUsers(mix, seed, vocab)
+        serve = drv.closed
+    else:
+        sched = traffic_lib.schedule(mix, seed, seconds, vocab)
+        warm, measured = sched.warm, sched.window
+        serve = drv.open
     log = StepLog(eng, config["stack"]["chunk"]) if trace else None
 
     def step():
@@ -494,7 +542,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
             eng.step()
 
     # the cell's own traffic for warmup_s, not measured
-    drv.open(sched.warm, mix["warmup_s"], eng.step, drain_s=0.0)
+    serve(warm, mix["warmup_s"], eng.step, drain_s=0.0)
     pool0 = eng.page_pool.stats() if eng.page_pool is not None else None
     n_compiles0 = compiles.count
     setup_compile_s, setup_hits = compiles.seconds, compiles.hits
@@ -504,7 +552,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
     profiler = _Profiler(seconds, trace_slice_s, clock) if trace else None
     stepper = step if profiler is None else profiler.wrap(step)
-    window, t0 = drv.open(sched.window, seconds, stepper)
+    window, t0 = serve(measured, seconds, stepper)
     n_compiles = compiles.count - n_compiles0
     if profiler is not None:
         profiler.stop()
@@ -556,12 +604,16 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
 
     # correctness, once the program's state is freed
     limits = config["limits"]
-    finish_for_check(eng, drv.sent, t0, limits["min_tokens"])
-    sample = sample_finished(drv.sent, t0, seed, limits["min_tokens"],
-                             limits["max_requests"])
-    del eng, drv, log, window
+    n_checked = mix.get("checked_requests", 1)
+    finish_for_check(eng, drv.sent, t0, limits["min_tokens"], n_checked)
+    picked = sample_finished(drv.sent, t0, seed, limits["min_tokens"],
+                             limits["max_requests"], n_checked)
+    info["slots_checked"] = len({s.slot for s in picked})
+    sample = [(np.asarray(s.req.prompt, np.int32),
+               np.asarray(s.req.out, np.int32)) for s in picked]
+    del eng, drv, log, window, picked
     gc.collect()
-    gaps = reference_gaps(config, seed, sample)
+    gaps = reference_gaps(cell.family, config, seed, sample)
     # the widest gap, and the mean where the widest swings too far from
     # seed to seed to separate the program from its control: each is
     # compared where the configuration gives it a limit, else only logged
@@ -573,11 +625,15 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *,
               for k, v in compared.items()}
     checks["tokens_checked"] = {"value": int(len(gaps)),
                                 "limit": limits["min_tokens"]}
+    if "checked_requests" in mix:
+        checks["requests_checked"] = {"value": len(sample),
+                                      "limit": n_checked}
     checks["failed_requests"] = {"value": failed, "limit": 0}
     info.update({k: v for k, v in stats.items() if k not in compared})
     correct = (bool(compared)
                and all(v <= limits[k] for k, v in compared.items())
-               and failed == 0 and len(gaps) >= limits["min_tokens"])
+               and failed == 0 and len(gaps) >= limits["min_tokens"]
+               and len(sample) >= n_checked)
     line = {"correct": bool(correct), "attempted": attempted,
             "failed": failed, "metrics": metrics, "device": dev}
     if breakdown is not None:

@@ -44,9 +44,13 @@ def main(argv=None) -> int:
         return 2
     harness.enable_compile_cache()
     cell = harness.load_cell(args.workload)
+    if cell.mix["kind"] != "open":
+        print(f"{args.workload}: a sweep of rates needs an open mix",
+              file=sys.stderr)
+        return 2
     clock = time.perf_counter
     compiles = harness.CompileClock()
-    eng = harness.build_engine(cell.config, args.seed)
+    eng = harness.build_engine(cell.family, cell.config, args.seed)
     harness.warm_shapes(eng, cell.config, args.seed)
     drv = harness.Sender(eng, clock)
     log = harness.StepLog(eng, cell.config["stack"]["chunk"])

@@ -1,6 +1,7 @@
 """Smoke-width copies of the benchmark's cells, for the CPU tests."""
 
 import copy
+import dataclasses
 
 
 #: published keys replaced to run a configuration at smoke width on a CPU
@@ -40,13 +41,18 @@ def shrink_config(config: dict) -> dict:
 
 
 def shrink_cell(cell, wide: bool = False):
-    c = copy.deepcopy(cell)
-    c.config = shrink_config(c.config)
+    c = dataclasses.replace(cell, config=shrink_config(cell.config),
+                            mix=copy.deepcopy(cell.mix))
     if wide:
         c.config.update(WIDE.get(c.config["program_model"], {}))
     m = c.mix
     m["warmup_s"] = 1
-    m["rate_per_s"] = 3.0
+    if m["kind"] == "closed":
+        m.update(users=c.config["stack"]["slots"], stagger_s=0.2)
+    if "checked_requests" in m:
+        m["checked_requests"] = c.config["stack"]["slots"]
+    else:
+        m["rate_per_s"] = 3.0
     if "prefix" in m:
         m["prefix"]["tokens"] = 64
     m["prompt"].update(median=40, min=32, max=96)

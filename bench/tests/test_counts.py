@@ -7,6 +7,8 @@ import pytest
 
 from bench import counts, harness
 
+DENSE = harness.load_family("dense")
+
 #: two layers, d 8, 2 query heads over 1 kv head of 4, FFN 16, vocab 10
 MODEL = dict(n_layers=2, d_model=8, n_heads=2, n_kv_heads=1, d_head=4,
              d_ff=16, vocab_size=10, act="silu")
@@ -17,16 +19,16 @@ PREFILL = counts.Rows(start=0, n=4, logits=False, kind="prefill")
 
 def test_matmul_params():
     # per layer: q 8·8 + k,v 2·8·4 + o 8·8 = 192; SwiGLU 3·8·16 = 384
-    assert counts.matmul_params(MODEL) == 2 * (192 + 384)
-    assert counts.matmul_params({**MODEL, "act": "gelu"}) == 2 * (192 + 256)
+    assert DENSE.matmul_params(MODEL) == 2 * (192 + 384)
+    assert DENSE.matmul_params({**MODEL, "act": "gelu"}) == 2 * (192 + 256)
 
 
 def test_rows_flops():
     # per token 2·1152 = 2304; attention 4·L·H·dh = 64 per context position;
     # head 2·8·10 = 160 once per row whose logits are read
-    assert counts.rows_flops(MODEL, [CHUNK]) == 2 * 2304 + 64 * (4 + 5) + 160
-    assert counts.rows_flops(MODEL, [DECODE]) == 2304 + 64 * 11 + 160
-    assert counts.rows_flops(MODEL, [PREFILL]) == 4 * 2304 + 64 * 10
+    assert DENSE.rows_flops(MODEL, [CHUNK]) == 2 * 2304 + 64 * (4 + 5) + 160
+    assert DENSE.rows_flops(MODEL, [DECODE]) == 2304 + 64 * 11 + 160
+    assert DENSE.rows_flops(MODEL, [PREFILL]) == 4 * 2304 + 64 * 10
 
 
 def test_plane_attn_work():
@@ -48,7 +50,7 @@ PEAKS = {"bf16_flops_per_s": 1000.0, "int8_ops_per_s": 2000.0,
 
 
 def _record(**kw):
-    cell = NS(model=MODEL)
+    cell = NS(model=MODEL, family=DENSE)
     base = dict(cell=cell, seconds=2.0, steps=[], traced_steps=[],
                 trace=None, peaks=PEAKS, spans=[], pool_window=None,
                 compiles=0)

@@ -85,3 +85,14 @@ def test_prefill_host_ms_needs_the_readback_span():
     assert read(_record(STEPS + [_span("engine.prefill", 10.1, 0.5, 1)])) \
         is None
     assert read(_record([])) is None
+
+
+def test_reserve_share_sums_the_reserve_spans_over_the_window():
+    spans = STEPS + [_span("engine.reserve", 10.1, 0.25, 1),
+                     _span("engine.reserve", 12.1, 0.05, 1)]
+    read = harness.load_reader("reserve_share")
+    assert read(_record(spans, seconds=2.0)) == pytest.approx(15.0)
+
+
+def test_reserve_share_without_reserve_spans_is_absent():
+    assert harness.load_reader("reserve_share")(_record(STEPS)) is None

@@ -29,10 +29,11 @@ def _cos(a, b):
 def test_engine_logits_match_reference(config):
     cell = {"qwen3-1.7b": "qwen3-1.7b.chat-shared",
             "starcoder2-3b": "starcoder2-3b.code-complete"}[config]
-    cfg = shrink_config(harness.load_cell(cell).config)
-    model = harness.model_dims(cfg)
+    cell = harness.load_cell(cell)
+    family, cfg = cell.family, shrink_config(cell.config)
+    model = family.dims(cfg)
     seed = 2**35 + 3
-    eng = harness.build_engine(cfg, seed)
+    eng = harness.build_engine(family, cfg, seed)
     eng.trace_logits = True
     rng = np.random.default_rng(0)
     chunk = cfg["stack"]["chunk"]
@@ -40,7 +41,7 @@ def test_engine_logits_match_reference(config):
     second = np.concatenate(
         [first[:chunk], rng.integers(0, model["vocab_size"], chunk,
                                      dtype=np.int32)])
-    w = weights.reference_weights(model, seed)
+    w = weights.reference_weights(family, model, seed)
     worst = 1.0
     for prompt in (first, second):
         eng.logit_trace.clear()
@@ -52,7 +53,7 @@ def test_engine_logits_match_reference(config):
         assert len(got) == len(req.out) == 4
         seq = np.concatenate([prompt, np.asarray(req.out[:-1], np.int32)])
         rows = np.arange(len(prompt) - 1, len(seq))
-        ref = reference.logits_at(model, w, seq, rows)
+        ref = reference.logits_at(family, model, w, seq, rows)
         for g, r in zip(got, ref):
             worst = min(worst, _cos(g[: model["vocab_size"]], r))
     assert worst > COS_MIN, worst
@@ -64,7 +65,7 @@ def test_int4_rounding_grid():
     """Each vector lands on at most 16 multiples of its own scale, each
     value within half a step of where it was."""
     x = np.random.default_rng(1).normal(size=(6, 128)).astype(np.float32)
-    r = np.asarray(reference._int4(x))
+    r = np.asarray(reference.int4(x))
     scale = np.abs(x).max(-1, keepdims=True) / 7
     steps = r / scale
     np.testing.assert_allclose(steps, np.round(steps), atol=1e-4)
@@ -77,19 +78,20 @@ def test_reference_rounds_what_the_configuration_states(kv_bits, q_bits):
     """The cache's int4 rounding of K and V, and of the query only where
     the configuration states it, each moves the logits; none of it runs
     over a 16-bit cache."""
-    cfg = shrink_config(harness.load_cell("qwen3-1.7b.chat-shared").config)
-    model = harness.model_dims(cfg)
-    w = weights.reference_weights(model, 7)
+    cell = harness.load_cell("qwen3-1.7b.chat-shared")
+    family, cfg = cell.family, shrink_config(cell.config)
+    model = family.dims(cfg)
+    w = weights.reference_weights(family, model, 7)
     toks = np.random.default_rng(2).integers(0, model["vocab_size"], 48)
     rows = np.arange(40, 48)
-    plain = reference.logits_at({**model, "kv_bits": 16, "q_bits": 16}, w,
-                                toks, rows)
-    got = reference.logits_at({**model, "kv_bits": kv_bits,
-                               "q_bits": q_bits}, w, toks, rows)
+    plain = reference.logits_at(family, {**model, "kv_bits": 16,
+                                         "q_bits": 16}, w, toks, rows)
+    got = reference.logits_at(family, {**model, "kv_bits": kv_bits,
+                                       "q_bits": q_bits}, w, toks, rows)
     if kv_bits == 16:
         np.testing.assert_array_equal(got, plain)
         return
     assert not np.allclose(got, plain, atol=1e-4)
-    kv_only = reference.logits_at({**model, "kv_bits": 4, "q_bits": 16}, w,
-                                  toks, rows)
+    kv_only = reference.logits_at(family, {**model, "kv_bits": 4,
+                                           "q_bits": 16}, w, toks, rows)
     assert np.allclose(got, kv_only, atol=1e-6) == (q_bits == 16)
